@@ -23,12 +23,12 @@ import dataclasses
 import itertools
 import os
 import time
-import warnings
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core.admm import ADMMConfig, Trace
 from repro.core.graph import Network, make_network
 from repro.core.problems import DATASETS, LeastSquaresProblem, allocate
@@ -45,37 +45,6 @@ from repro.methods import (
 MODES = ("auto", "serial", "batched", "sharded")
 
 __all__ = ["Case", "SweepSpec", "SweepResult", "run_sweep"]
-
-_cache_enabled = False
-
-
-def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache: a sweep's one-trace-per-group
-    compile is its dominant cold cost, so repeat benchmark invocations
-    load the compiled scan from disk (EXPERIMENTS.md §Perf). Opt out with
-    REPRO_JAX_CACHE=0; relocate with REPRO_JAX_CACHE_DIR.
-    """
-    global _cache_enabled
-    if _cache_enabled or os.environ.get("REPRO_JAX_CACHE", "1") == "0":
-        return
-    _cache_enabled = True
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("REPRO_JAX_CACHE_DIR", ".jax_cache"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-    except Exception as exc:
-        # Older jax without the knobs: compile per process as before — but
-        # say so ONCE, so a cold-compile wall-clock regression in CI is
-        # explainable from the log instead of silent.
-        warnings.warn(
-            "persistent XLA compilation cache unavailable "
-            f"({type(exc).__name__}: {exc}); sweeps will compile per "
-            "process",
-            RuntimeWarning,
-            stacklevel=2,
-        )
 
 # Every registered method kernel is sweepable (DESIGN.md §8).
 METHODS = tuple(KERNELS)
@@ -376,7 +345,7 @@ def run_sweep(
     if not cases:
         raise ValueError("empty sweep")
     mode = _resolve_mode(serial, mode)
-    _enable_compilation_cache()
+    enable_compilation_cache()
 
     t0 = time.perf_counter()
     net_cache: Dict[tuple, Network] = {}

@@ -17,6 +17,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_step
+from repro.compile_cache import enable_compilation_cache
 from repro.configs import get_config, get_smoke_config
 from repro.data import agent_token_streams, make_lm_batch
 from repro.distributed import ConsensusConfig, ConsensusRuntime, PlainRuntime
@@ -83,7 +85,9 @@ def run_consensus(model, args) -> dict:
     )
     rt = ConsensusRuntime(model, ccfg, _mesh_1dev())
     state = rt.init_state(jax.random.key(args.seed))
-    step = jax.jit(rt.train_step)
+    # The state is donated: x, y and z are rewritten every step, and at
+    # full width two live copies of them do not fit one chip.
+    step = jax.jit(rt.train_step, donate_argnums=0)
     code = ccfg.code()
     sup = [code.support(j) for j in range(args.ecns)]
     # disjoint stream per agent (paper's allocation)
@@ -140,6 +144,10 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
+    ap.add_argument(
+        "--remat", choices=("none", "full", "dots"), default=None,
+        help="activation checkpointing of the layer scan (default: config's)",
+    )
     # consensus
     ap.add_argument("--agents", type=int, default=2)
     ap.add_argument("--ecns", type=int, default=4)
@@ -157,7 +165,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.remat is not None:
+        cfg = dataclasses.replace(cfg, remat=args.remat)
     model = get_model(cfg)
+    enable_compilation_cache()
     print(
         f"training {args.arch} ({'smoke' if args.smoke else 'full'}) "
         f"mode={args.mode} params={cfg.param_count():,}"

@@ -26,7 +26,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.admm import Trace
@@ -302,7 +301,7 @@ def _sharded_fn(
     GSPMD, XLA walls the op off and reshards its operands every scan
     iteration (measured ~50x slower); under shard_map each device runs
     the whole vmapped scan on its local R/D runs and the Pallas call
-    never sees a partitioned operand. check_rep=False for the same
+    never sees a partitioned operand. check_vma=False for the same
     reason (pallas_call has no replication rule). Nothing in the scan
     crosses the runs axis, so per-run math — and the outputs — are
     bitwise identical to the single-device vmap.
@@ -314,12 +313,12 @@ def _sharded_fn(
         tuple(P("runs") for _ in range(n_steps)),
     )
     out_spec = (P("runs"), P("runs"), (P("runs"), P("runs"), P("runs")))
-    fn = shard_map(
+    fn = jax.shard_map(
         jax.vmap(_compose(kernel, statics_key)),
         mesh=mesh,
         in_specs=spec,
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
 
@@ -345,12 +344,12 @@ def _sharded_reduced_fn(
         tuple(P("runs") for _ in range(n_consts)),
         tuple(P("runs") for _ in range(n_steps + 1)),  # +1: clock steps
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         jax.vmap(_compose_reduced(kernel, statics_key, spec)),
         mesh=mesh,
         in_specs=in_spec,
         out_specs=P("runs"),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
 

@@ -151,9 +151,7 @@ def test_coded_kernels_f64_interpret_parity():
     """Under x64 the interpret-mode kernels accumulate in f64 end to end
     (the convergence suite's precision floor): parity vs the oracle at
     f64-tight tolerance."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         J, n = 5, 3000
         rng = np.random.default_rng(7)
         msgs = jnp.asarray(rng.standard_normal((J, n)))

@@ -6,9 +6,8 @@ every execution tier, with sharded == batched BITWISE; chunked streaming
 execution is invisible in the outputs; and the results plumbing
 (`run_sweep`/`reduce_mean`/`emit_rows`) consumes pre-reduced grid arrays.
 Satellite regressions ride along: the vectorized `resample_runs` must be
-bit-identical to the per-run searchsorted loop, integer-typed fields must
-promote to float before CI math, and `_enable_compilation_cache` must
-warn (not silently pass) when the cache knobs are unavailable.
+bit-identical to the per-run searchsorted loop, and integer-typed fields
+must promote to float before CI math.
 """
 
 import dataclasses
@@ -356,27 +355,6 @@ def test_integer_fields_promote_to_float():
     assert np.issubdtype(mean.dtype, np.floating)
     np.testing.assert_allclose(mean, [1.5])
     assert ci[0] > 0.0
-
-
-def test_compilation_cache_warns_when_unavailable(monkeypatch):
-    """Satellite: the cache helper must warn once instead of silently
-    swallowing a missing-knob failure."""
-    import warnings
-
-    from repro.experiments import sweep as sweep_mod
-
-    monkeypatch.setattr(sweep_mod, "_cache_enabled", False)
-
-    def boom(*a, **kw):
-        raise ValueError("no such config option")
-
-    monkeypatch.setattr(sweep_mod.jax.config, "update", boom)
-    with pytest.warns(RuntimeWarning, match="compilation cache"):
-        sweep_mod._enable_compilation_cache()
-    # the flag latched: a second call neither warns nor retries
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        sweep_mod._enable_compilation_cache()
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2, reason="needs a device mesh")

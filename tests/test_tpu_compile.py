@@ -1,0 +1,114 @@
+"""The fused ADMM kernels compile natively for a TPU v5e chip.
+
+Interpret mode, which every other kernel test uses on the CPU, cannot see
+what the chip's compiler refuses: slices off the lane tiling, too much
+fast memory, 64-bit index arithmetic. These tests hand the public `ops`
+wrappers to the installed TPU compiler against a described (not
+attached) v5e topology and check that each program holds the Mosaic
+kernel (`tpu_custom_call`). Nothing runs, so nothing here says anything
+about results or times.
+
+The topology is described only inside a fixture: only one process may
+load the TPU library at a time, and it keeps it until it exits. The
+compiles run with x64 off, as on the chip (Mosaic refuses the 64-bit
+index maps that the suite-wide x64 of `conftest.py` would trace), and
+with the persistent compilation cache off, since a program compiled for
+a described chip cannot be read back from it.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+UPDATE = ops.coded_admm_update.__wrapped__
+COMBINE = ops.coded_combine.__wrapped__
+
+
+@pytest.fixture(scope="module")
+def topo():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # no TPU compiler, or its library is held
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def native(monkeypatch):
+    """Lower the `ops` wrappers as the chip does: native Pallas, f32."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+def _shapes(one_chip, op, J, n, mask, runs=()):
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(
+            (*runs, *shape), jnp.float32, sharding=one_chip
+        )
+
+    m = f32(J) if mask else None
+    if op == "combine":
+        return (f32(J, n), f32(J), m)
+    return (f32(J, n), f32(J), f32(n), f32(n), f32(n), f32(), f32(), m)
+
+
+def _assert_native(fn, args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+OPS = {"update": UPDATE, "combine": COMBINE}
+
+
+@pytest.mark.parametrize("mask", [True, False], ids=["mask", "no_mask"])
+@pytest.mark.parametrize("op", sorted(OPS))
+def test_kernel_compiles_at_usps_width(native, one_chip, op, mask):
+    """J = 3 ECNs over the usps parameter vector (p*d = 640)."""
+    fn = functools.partial(OPS[op], block_n=ops.fit_block_n(640))
+    _assert_native(fn, _shapes(one_chip, op, 3, 640, mask))
+
+
+@pytest.mark.parametrize("op", sorted(OPS))
+def test_kernel_pads_tiny_vector_to_one_lane_tile(native, one_chip, op):
+    """The synthetic problem's 3-float parameter, padded to 128 lanes."""
+    fn = functools.partial(OPS[op], block_n=ops.fit_block_n(3))
+    _assert_native(fn, _shapes(one_chip, op, 6, 3, True))
+
+
+@pytest.mark.parametrize("n", [3, 640])
+def test_update_vmapped_over_runs(native, one_chip, n):
+    """The batched sweep's form: J = 6, one kernel call per run, 16 runs."""
+    fn = jax.vmap(functools.partial(UPDATE, block_n=ops.fit_block_n(n)))
+    _assert_native(fn, _shapes(one_chip, "update", 6, n, True, runs=(16,)))
+
+
+@pytest.mark.parametrize("op", sorted(OPS))
+def test_kernel_compiles_at_large_n(native, one_chip, op):
+    """J = 16 over a 2**20 vector in 16384-lane tiles (~1.3 MB of VMEM)."""
+    fn = functools.partial(OPS[op], block_n=16_384)
+    _assert_native(fn, _shapes(one_chip, op, 16, 2**20, True))
