@@ -13,6 +13,9 @@ visible device the vmapped runs axis is additionally laid out over a
 picked by ``mode`` ("auto"/"serial"/"batched"/"sharded"). Host-side
 sampling (topology, data allocation, straggler times, decode vectors)
 stays per-run and is stacked into the batched scan's per-step inputs.
+Each call names its host phases (materialize, prepare, stack, transfer,
+execute) with profiler spans, which exist only while a profile is being
+captured (DESIGN.md §16).
 
 Timing of the serial-vs-batched paths is recorded in EXPERIMENTS.md §Perf.
 """
@@ -347,58 +350,60 @@ def run_sweep(
     mode = _resolve_mode(serial, mode)
     enable_compilation_cache()
 
-    t0 = time.perf_counter()
-    net_cache: Dict[tuple, Network] = {}
-    prob_cache: Dict[tuple, LeastSquaresProblem] = {}
-    mats = [_materialize(c, net_cache, prob_cache) for c in cases]
+    with jax.profiler.TraceAnnotation("repro.sweep", runs=len(cases)):
+        t0 = time.perf_counter()
+        net_cache: Dict[tuple, Network] = {}
+        prob_cache: Dict[tuple, LeastSquaresProblem] = {}
+        with jax.profiler.TraceAnnotation("repro.sweep.materialize"):
+            mats = [_materialize(c, net_cache, prob_cache) for c in cases]
 
-    # Group by static signature, preserving first-seen order.
-    groups: Dict[tuple, List[int]] = {}
-    for idx, (case, (_net, prob)) in enumerate(zip(cases, mats)):
-        groups.setdefault(_signature(case, prob), []).append(idx)
+        # Group by static signature, preserving first-seen order.
+        groups: Dict[tuple, List[int]] = {}
+        for idx, (case, (_net, prob)) in enumerate(zip(cases, mats)):
+            groups.setdefault(_signature(case, prob), []).append(idx)
 
-    traces: List[Optional[Trace]] = [None] * len(cases)
-    rows: List[Optional[dict]] = [None] * len(cases)
-    group_meta: List[Tuple[tuple, int]] = []
-    for sig, idxs in groups.items():
-        gcases = [cases[i] for i in idxs]
-        gnets = [mats[i][0] for i in idxs]
-        gprobs = [mats[i][1] for i in idxs]
-        if verbose:
-            print(
-                f"[sweep] {sig[0]} group x{len(idxs)} ({mode}): {sig[1:]}"
+        traces: List[Optional[Trace]] = [None] * len(cases)
+        rows: List[Optional[dict]] = [None] * len(cases)
+        group_meta: List[Tuple[tuple, int]] = []
+        for sig, idxs in groups.items():
+            gcases = [cases[i] for i in idxs]
+            gnets = [mats[i][0] for i in idxs]
+            gprobs = [mats[i][1] for i in idxs]
+            if verbose:
+                print(
+                    f"[sweep] {sig[0]} group x{len(idxs)} ({mode}): {sig[1:]}"
+                )
+            gout = _dispatch_group(
+                gcases[0].method, gcases, gnets, gprobs, mode,
+                reductions=reductions,
             )
-        gout = _dispatch_group(
-            gcases[0].method, gcases, gnets, gprobs, mode,
-            reductions=reductions,
-        )
+            if reductions is not None:
+                # Scatter the group's (group_size, ...) summary arrays back
+                # into grid order; stacked once below.
+                for j, i in enumerate(idxs):
+                    rows[i] = {k: v[j] for k, v in gout.items()}
+            else:
+                for i, tr in zip(idxs, gout):
+                    traces[i] = tr
+            group_meta.append((sig, len(idxs)))
+
+        reduced = None
         if reductions is not None:
-            # Scatter the group's (group_size, ...) summary arrays back
-            # into grid order; stacked once below.
-            for j, i in enumerate(idxs):
-                rows[i] = {k: v[j] for k, v in gout.items()}
-        else:
-            for i, tr in zip(idxs, gout):
-                traces[i] = tr
-        group_meta.append((sig, len(idxs)))
+            keys = rows[0].keys()
+            if any(r.keys() != keys for r in rows[1:]):
+                raise ValueError(
+                    "sweep groups produced different reduction keys; all "
+                    "groups must share one Reduction spec"
+                )
+            reduced = {k: np.stack([r[k] for r in rows]) for k in keys}
+            traces = []
 
-    reduced = None
-    if reductions is not None:
-        keys = rows[0].keys()
-        if any(r.keys() != keys for r in rows[1:]):
-            raise ValueError(
-                "sweep groups produced different reduction keys; all "
-                "groups must share one Reduction spec"
-            )
-        reduced = {k: np.stack([r[k] for r in rows]) for k in keys}
-        traces = []
-
-    return SweepResult(
-        cases=cases,
-        traces=traces,  # type: ignore[arg-type]
-        groups=group_meta,
-        wall_s=time.perf_counter() - t0,
-        mode=mode,
-        n_devices=len(jax.devices()),
-        reduced=reduced,
-    )
+        return SweepResult(
+            cases=cases,
+            traces=traces,  # type: ignore[arg-type]
+            groups=group_meta,
+            wall_s=time.perf_counter() - t0,
+            mode=mode,
+            n_devices=len(jax.devices()),
+            reduced=reduced,
+        )

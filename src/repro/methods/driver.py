@@ -182,6 +182,7 @@ def _stack_batch(
     nets: Sequence[Network],
     cfgs: Sequence,
     iters: int,
+    clock: bool = False,
 ) -> Tuple[List[Prepared], dict, Tuple[np.ndarray, ...], Tuple[np.ndarray, ...]]:
     """Prepare R runs and stack them on a leading runs axis (host-side).
 
@@ -189,7 +190,9 @@ def _stack_batch(
     (e.g. the masked gather bound MU) are reconciled with ``max`` so runs
     whose *runtime* value differs (mixed straggler tolerance S in a fig5
     grid) still share the trace. Raises ValueError on mixed statics —
-    `repro.experiments.sweep.run_sweep` groups by signature first.
+    `repro.experiments.sweep.run_sweep` groups by signature first. With
+    ``clock`` the stacked `_clock_steps` ride along as the last step input
+    (the streaming tier's layout, `_compose_reduced`).
     """
     R = len(problems)
     if not (len(nets) == len(cfgs) == R):
@@ -204,25 +207,36 @@ def _stack_batch(
             f"{kernel.name} static_signature() first"
         )
 
-    preps = [
-        kernel.prepare(p, n, c, iters)
-        for p, n, c in zip(problems, nets, cfgs)
-    ]
+    with jax.profiler.TraceAnnotation("repro.sweep.prepare", runs=R):
+        preps = [
+            kernel.prepare(p, n, c, iters)
+            for p, n, c in zip(problems, nets, cfgs)
+        ]
     statics = dict(preps[0].statics)
     if any(pr.statics != statics for pr in preps[1:]):
         raise ValueError("equal signatures produced unequal statics")
     for key in preps[0].max_statics:
         statics[key] = max(pr.max_statics[key] for pr in preps)
 
-    consts = tuple(
-        np.stack([np.asarray(pr.consts[i]) for pr in preps])
-        for i in range(len(preps[0].consts))
-    )
-    steps = tuple(
-        np.stack([np.asarray(pr.steps[i]) for pr in preps])
-        for i in range(len(preps[0].steps))
-    )
+    with jax.profiler.TraceAnnotation("repro.sweep.stack"):
+        consts = _stack([pr.consts for pr in preps])
+        steps = _stack([pr.steps for pr in preps])
+        if clock:
+            steps += (np.stack([_clock_steps(pr) for pr in preps]),)
     return preps, statics, consts, steps
+
+
+def _stack(per_run: Sequence[Sequence]) -> Tuple[np.ndarray, ...]:
+    """Stack each position of the runs' input tuples on a runs axis."""
+    return tuple(
+        np.stack([np.asarray(r[i]) for r in per_run])
+        for i in range(len(per_run[0]))
+    )
+
+
+def _nbytes(arrays: Sequence[np.ndarray]) -> int:
+    """Host bytes handed to the device: the transfer span's ``bytes``."""
+    return sum(a.nbytes for a in arrays)
 
 
 def _unstack_traces(preps: List[Prepared], x, z, metrics) -> List[Trace]:
@@ -248,22 +262,21 @@ def run_batch(
     numpy arrays with a leading runs axis (DESIGN.md §12).
     """
     preps, statics, consts, steps = _stack_batch(
-        kernel, problems, nets, cfgs, iters
+        kernel, problems, nets, cfgs, iters, clock=reductions is not None
     )
-    if reductions is not None:
-        fn = _batch_reduced_fn(kernel, _statics_key(statics), reductions)
-        out = fn(
+    with jax.profiler.TraceAnnotation(
+        "repro.sweep.transfer", runs=len(preps), bytes=_nbytes(consts + steps)
+    ):
+        args = (
             tuple(jnp.asarray(c) for c in consts),
-            tuple(jnp.asarray(s) for s in steps)
-            + (jnp.asarray(np.stack([_clock_steps(p) for p in preps])),),
+            tuple(jnp.asarray(s) for s in steps),
         )
-        return {k: np.asarray(v) for k, v in out.items()}
-    fn = _batch_fn(kernel, _statics_key(statics))
-    x, z, metrics = fn(
-        tuple(jnp.asarray(c) for c in consts),
-        tuple(jnp.asarray(s) for s in steps),
-    )
-    return _unstack_traces(preps, x, z, metrics)
+    with jax.profiler.TraceAnnotation("repro.sweep.execute"):
+        if reductions is not None:
+            fn = _batch_reduced_fn(kernel, _statics_key(statics), reductions)
+            return {k: np.asarray(v) for k, v in fn(*args).items()}
+        x, z, metrics = _batch_fn(kernel, _statics_key(statics))(*args)
+        return _unstack_traces(preps, x, z, metrics)
 
 
 # --------------------------------------------------------------------------
@@ -415,7 +428,8 @@ def _run_reduced_chunked(
             bound[key] = max(bound.get(key, 0), int(val))
 
     # One probe prepare: fixes the shared statics and sizes the chunks.
-    prep0 = kernel.prepare(problems[0], nets[0], cfgs[0], iters)
+    with jax.profiler.TraceAnnotation("repro.sweep.prepare", runs=1):
+        prep0 = kernel.prepare(problems[0], nets[0], cfgs[0], iters)
     if set(prep0.max_statics) != set(bound):
         raise ValueError(
             f"{kernel.name}.max_statics_bound() keys {sorted(bound)} != "
@@ -431,19 +445,23 @@ def _run_reduced_chunked(
     mesh = _runs_mesh()
     layout = AxisLayout(mesh, data=("runs",), model="model")
     donate = jax.default_backend() in ("tpu", "gpu")
+    fn = _sharded_reduced_fn(
+        kernel, _statics_key(statics), spec, D,
+        len(prep0.consts), len(prep0.steps), donate,
+    )
     del prep0  # the probe's schedules are re-prepared with its chunk
 
     chunk = _chunk_runs(-(-R // D) * D, D, max(per_run, 1))
-    fn = None
     outs: List[Dict[str, np.ndarray]] = []
     for lo in range(0, R, chunk):
         hi = min(lo + chunk, R)
-        preps = [
-            kernel.prepare(p, n, c, iters)
-            for p, n, c in zip(
-                problems[lo:hi], nets[lo:hi], cfgs[lo:hi]
-            )
-        ]
+        with jax.profiler.TraceAnnotation("repro.sweep.prepare", runs=hi - lo):
+            preps = [
+                kernel.prepare(p, n, c, iters)
+                for p, n, c in zip(
+                    problems[lo:hi], nets[lo:hi], cfgs[lo:hi]
+                )
+            ]
         for pr in preps:
             if pr.statics != _shared_statics(statics, pr):
                 raise ValueError(
@@ -456,45 +474,57 @@ def _run_reduced_chunked(
                         f"{key}: prepared {val} > bound {statics[key]}"
                     )
         n = hi - lo
-        csl = tuple(
-            np.stack([np.asarray(pr.consts[i]) for pr in preps])
-            for i in range(len(preps[0].consts))
-        )
-        ssl = tuple(
-            np.stack([np.asarray(pr.steps[i]) for pr in preps])
-            for i in range(len(preps[0].steps))
-        ) + (np.stack([_clock_steps(pr) for pr in preps]),)
+        with jax.profiler.TraceAnnotation("repro.sweep.stack"):
+            csl = _pad_runs(_stack([pr.consts for pr in preps]), n, D)
+            ssl = _pad_runs(
+                _stack([pr.steps for pr in preps])
+                + (np.stack([_clock_steps(pr) for pr in preps]),),
+                n, D,
+            )
         del preps
-        if fn is None:
-            fn = _sharded_reduced_fn(
-                kernel, _statics_key(statics), spec, D,
-                len(csl), len(ssl) - 1, donate,
-            )
-        pad = -(-n // D) * D - n
-        if pad:  # repeat the last run; its outputs are sliced off below
-            csl = tuple(
-                np.concatenate([a, np.repeat(a[-1:], pad, axis=0)])
-                for a in csl
-            )
-            ssl = tuple(
-                np.concatenate([a, np.repeat(a[-1:], pad, axis=0)])
-                for a in ssl
-            )
-        cspec, sspec = batch_specs((csl, ssl), layout)
-        put_c = tuple(
-            jax.device_put(a, NamedSharding(mesh, s))
-            for a, s in zip(csl, cspec)
-        )
-        put_s = tuple(
-            jax.device_put(a, NamedSharding(mesh, s))
-            for a, s in zip(ssl, sspec)
-        )
+        put_c, put_s = _put_sharded(csl, ssl, -(-n // D) * D, mesh, layout)
         del csl, ssl  # the chunk's host copies die before the next one
-        out = fn(put_c, put_s)
-        outs.append({k: np.asarray(v)[:n] for k, v in out.items()})
+        with jax.profiler.TraceAnnotation("repro.sweep.execute"):
+            out = fn(put_c, put_s)
+            outs.append({k: np.asarray(v)[:n] for k, v in out.items()})
     return {
         k: np.concatenate([o[k] for o in outs]) for k in outs[0]
     }
+
+
+def _pad_runs(
+    arrays: Tuple[np.ndarray, ...], n: int, D: int
+) -> Tuple[np.ndarray, ...]:
+    """Pad the n-run axis to a multiple of D by repeating the last run
+    (its outputs are sliced off after the dispatch)."""
+    pad = -(-n // D) * D - n
+    if not pad:
+        return arrays
+    return tuple(
+        np.concatenate([a, np.repeat(a[-1:], pad, axis=0)]) for a in arrays
+    )
+
+
+def _put_sharded(consts, steps, runs: int, mesh: Mesh, layout: AxisLayout):
+    """Place ``runs`` stacked host runs (padding included) on the runs
+    mesh, in a transfer span.
+
+    PartitionSpec is tuple-like, so zip over the inferred specs rather
+    than tree-mapping across them."""
+    cspec, sspec = batch_specs((consts, steps), layout)
+    with jax.profiler.TraceAnnotation(
+        "repro.sweep.transfer", runs=runs, bytes=_nbytes(consts + steps)
+    ):
+        return (
+            tuple(
+                jax.device_put(a, NamedSharding(mesh, s))
+                for a, s in zip(consts, cspec)
+            ),
+            tuple(
+                jax.device_put(a, NamedSharding(mesh, s))
+                for a, s in zip(steps, sspec)
+            ),
+        )
 
 
 def _shared_statics(statics: dict, prep: Prepared) -> dict:
@@ -567,32 +597,14 @@ def run_sharded(
     outs: List[Tuple] = []
     for lo in range(0, R, chunk):
         n = min(chunk, R - lo)
-        csl = tuple(a[lo : lo + n] for a in consts)
-        ssl = tuple(a[lo : lo + n] for a in steps)
-        pad = -(-n // D) * D - n
-        if pad:  # repeat the last run; its outputs are sliced off below
-            csl = tuple(
-                np.concatenate([a, np.repeat(a[-1:], pad, axis=0)])
-                for a in csl
+        with jax.profiler.TraceAnnotation("repro.sweep.stack"):
+            csl = _pad_runs(tuple(a[lo : lo + n] for a in consts), n, D)
+            ssl = _pad_runs(tuple(a[lo : lo + n] for a in steps), n, D)
+        put_c, put_s = _put_sharded(csl, ssl, -(-n // D) * D, mesh, layout)
+        with jax.profiler.TraceAnnotation("repro.sweep.execute"):
+            x, z, (acc, te, ze) = fn(put_c, put_s)
+            outs.append(
+                tuple(np.asarray(o)[:n] for o in (x, z, acc, te, ze))
             )
-            ssl = tuple(
-                np.concatenate([a, np.repeat(a[-1:], pad, axis=0)])
-                for a in ssl
-            )
-        # PartitionSpec is tuple-like, so zip over the inferred specs
-        # rather than tree-mapping across them.
-        cspec, sspec = batch_specs((csl, ssl), layout)
-        put_c = tuple(
-            jax.device_put(a, NamedSharding(mesh, s))
-            for a, s in zip(csl, cspec)
-        )
-        put_s = tuple(
-            jax.device_put(a, NamedSharding(mesh, s))
-            for a, s in zip(ssl, sspec)
-        )
-        x, z, (acc, te, ze) = fn(put_c, put_s)
-        outs.append(
-            tuple(np.asarray(o)[:n] for o in (x, z, acc, te, ze))
-        )
     cat = [np.concatenate([o[i] for o in outs]) for i in range(5)]
     return _unstack_traces(preps, cat[0], cat[1], (cat[2], cat[3], cat[4]))
