@@ -183,6 +183,8 @@ def trainer_phase() -> None:
 
 def sharded_phase() -> None:
     """fig5 over four chips (``mode="sharded"``) against one (batched)."""
+    import jax
+
     from repro.methods import driver
 
     spans = []
@@ -191,11 +193,15 @@ def sharded_phase() -> None:
     def spy(*key):
         fn = build(*key)
 
-        def call(consts, steps):
+        def call(*args):
             spans.append(
-                {d.id for a in (*consts, *steps) for d in a.sharding.device_set}
+                {
+                    d.id
+                    for a in jax.tree.leaves(args)
+                    for d in a.sharding.device_set
+                }
             )
-            return fn(consts, steps)
+            return fn(*args)
 
         return call
 

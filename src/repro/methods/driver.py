@@ -13,15 +13,16 @@ one device is visible (DESIGN.md §9). A fourth backend, the TPU mesh runtime
 (`repro.distributed.consensus`, DESIGN.md §3), shares the algorithmic
 core but owns its sharding-aware state layout.
 
-Jitted executables are cached per (kernel, statics) pair, on top of the
-persistent XLA compilation cache enabled by `repro.experiments.sweep`.
+Jitted executables are cached per kernel, statics and (batched tiers)
+const-table layout (`_Stacked`), on top of the persistent XLA
+compilation cache enabled by `repro.experiments.sweep`.
 """
 
 from __future__ import annotations
 
 import os
 from functools import lru_cache
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -110,14 +111,35 @@ def _clock_steps(prep: Prepared) -> np.ndarray:
     )
 
 
+def _batched(run, shared: Tuple[bool, ...]):
+    """vmap of a composed run over a `_Stacked` group's arguments.
+
+    Each run's consts tuple is rebuilt on the device: at the ``shared``
+    positions its row ``index`` of the table, elsewhere its own stacked
+    const. The rows are taken once, before the scan. A group that shares
+    nothing takes the plain vmap: (consts, steps), no tables, no index."""
+    vrun = jax.vmap(run)
+    if not any(shared):
+        return vrun
+
+    def fn(tables, index, consts, steps):
+        t, c = iter(tables), iter(consts)
+        full = tuple(next(t)[index] if s else next(c) for s in shared)
+        return vrun(full, steps)
+
+    return fn
+
+
 @lru_cache(maxsize=None)
 def _serial_fn(kernel: MethodKernel, statics_key: tuple):
     return jax.jit(_compose(kernel, statics_key))
 
 
 @lru_cache(maxsize=None)
-def _batch_fn(kernel: MethodKernel, statics_key: tuple):
-    return jax.jit(jax.vmap(_compose(kernel, statics_key)))
+def _batch_fn(
+    kernel: MethodKernel, statics_key: tuple, shared: Tuple[bool, ...]
+):
+    return jax.jit(_batched(_compose(kernel, statics_key), shared))
 
 
 @lru_cache(maxsize=None)
@@ -129,9 +151,14 @@ def _serial_reduced_fn(
 
 @lru_cache(maxsize=None)
 def _batch_reduced_fn(
-    kernel: MethodKernel, statics_key: tuple, spec: Reduction
+    kernel: MethodKernel,
+    statics_key: tuple,
+    spec: Reduction,
+    shared: Tuple[bool, ...],
 ):
-    return jax.jit(jax.vmap(_compose_reduced(kernel, statics_key, spec)))
+    return jax.jit(
+        _batched(_compose_reduced(kernel, statics_key, spec), shared)
+    )
 
 
 def _to_trace(prep: Prepared, x, z, metrics) -> Trace:
@@ -176,6 +203,68 @@ def run_serial(
     return _to_trace(prep, x, z, metrics)
 
 
+class _Stacked(NamedTuple):
+    """A dispatch group's host inputs, the runs on the leading axis.
+
+    A const that several runs hold as the same host object (the grid
+    points of one seed share its `LeastSquaresProblem`) ships once, as a
+    row of a table; ``index`` gives each run its row, and `_batched`
+    takes the rows on the device. ``shared`` marks the positions of
+    `Prepared.consts` that are tables: a static of the executable. Where
+    no position is shared there are no tables and no index.
+    """
+
+    shared: Tuple[bool, ...]
+    tables: Tuple[np.ndarray, ...]  # (U, ...) each: the shared positions
+    index: Optional[np.ndarray]  # (R,) int32: each run's row of the tables
+    consts: Tuple[np.ndarray, ...]  # (R, ...) each: the other positions
+    steps: Tuple[np.ndarray, ...]  # (R, iters, ...) each
+
+    @property
+    def per_run(self) -> tuple:
+        """The arguments with a runs axis: what chunks slice and pad."""
+        own = (self.consts, self.steps)
+        return own if self.index is None else (self.index, *own)
+
+    @property
+    def args(self) -> tuple:
+        """The batched executables' arguments, in their order."""
+        if self.index is None:
+            return self.per_run
+        return (self.tables, *self.per_run)
+
+
+def _stack_consts(per_run: Sequence[Sequence], copies: int = 1):
+    """(shared, tables, index, consts) of the runs' const tuples.
+
+    A position is shared where the runs hold fewer distinct objects than
+    there are runs, by identity: free, and exact. One index serves every
+    shared position: a row per distinct combination of their objects.
+    Each table ships ``copies`` times (once per device of the sharded
+    tier), against one row per run padded to a multiple of ``copies``.
+    Where the tables ship no fewer rows than that, every position stacks
+    per run, with no table and no index."""
+    R, n = len(per_run), len(per_run[0])
+    shared = tuple(len({id(r[i]) for r in per_run}) < R for i in range(n))
+    rows: Dict[tuple, int] = {}
+    index = np.array(
+        [
+            rows.setdefault(
+                tuple(id(c) for c, s in zip(r, shared) if s), len(rows)
+            )
+            for r in per_run
+        ],
+        dtype=np.int32,
+    )
+    if len(rows) * copies >= -(-R // copies) * copies or not any(shared):
+        return (False,) * n, (), None, _stack(per_run)
+    # Rows are numbered in order of first appearance.
+    firsts = [per_run[i] for i in np.unique(index, return_index=True)[1]]
+    tables = _stack([[c for c, s in zip(r, shared) if s] for r in firsts])
+    consts = _stack([[c for c, s in zip(r, shared) if not s] for r in per_run])
+    return shared, tables, index, consts
+
+
 def _stack_batch(
     kernel: MethodKernel,
     problems: Sequence[LeastSquaresProblem],
@@ -183,7 +272,8 @@ def _stack_batch(
     cfgs: Sequence,
     iters: int,
     clock: bool = False,
-) -> Tuple[List[Prepared], dict, Tuple[np.ndarray, ...], Tuple[np.ndarray, ...]]:
+    copies: int = 1,
+) -> Tuple[List[Prepared], dict, _Stacked]:
     """Prepare R runs and stack them on a leading runs axis (host-side).
 
     All runs must share the kernel's static signature; ``max_statics``
@@ -192,7 +282,9 @@ def _stack_batch(
     grid) still share the trace. Raises ValueError on mixed statics —
     `repro.experiments.sweep.run_sweep` groups by signature first. With
     ``clock`` the stacked `_clock_steps` ride along as the last step input
-    (the streaming tier's layout, `_compose_reduced`).
+    (the streaming tier's layout, `_compose_reduced`). Consts that runs
+    share by identity become tables shipped ``copies`` times
+    (`_stack_consts`).
     """
     R = len(problems)
     if not (len(nets) == len(cfgs) == R):
@@ -219,11 +311,13 @@ def _stack_batch(
         statics[key] = max(pr.max_statics[key] for pr in preps)
 
     with jax.profiler.TraceAnnotation("repro.sweep.stack"):
-        consts = _stack([pr.consts for pr in preps])
+        shared, tables, index, consts = _stack_consts(
+            [pr.consts for pr in preps], copies
+        )
         steps = _stack([pr.steps for pr in preps])
         if clock:
             steps += (np.stack([_clock_steps(pr) for pr in preps]),)
-    return preps, statics, consts, steps
+    return preps, statics, _Stacked(shared, tables, index, consts, steps)
 
 
 def _stack(per_run: Sequence[Sequence]) -> Tuple[np.ndarray, ...]:
@@ -234,9 +328,22 @@ def _stack(per_run: Sequence[Sequence]) -> Tuple[np.ndarray, ...]:
     )
 
 
-def _nbytes(arrays: Sequence[np.ndarray]) -> int:
+def _nbytes(tree) -> int:
     """Host bytes handed to the device: the transfer span's ``bytes``."""
-    return sum(a.nbytes for a in arrays)
+    return sum(a.nbytes for a in jax.tree.leaves(tree))
+
+
+def _transfer_span(per_run, tables=(), sharing: bool = False):
+    """The transfer span of a dispatch's host inputs: ``runs``, the rows
+    of ``per_run``; ``tables``, the rows of ``tables`` shipped (each run
+    ships its own data where its group is not ``sharing``; a later chunk
+    of one that is ships none); ``bytes``, the host bytes of both."""
+    runs = len(jax.tree.leaves(per_run)[0])
+    n = len(tables[0]) if tables else 0 if sharing else runs
+    return jax.profiler.TraceAnnotation(
+        "repro.sweep.transfer", runs=runs, tables=n,
+        bytes=_nbytes((tables, per_run)),
+    )
 
 
 def _unstack_traces(preps: List[Prepared], x, z, metrics) -> List[Trace]:
@@ -261,21 +368,17 @@ def run_batch(
     Returns per-run `Trace`s, or — with ``reductions`` — one dict of
     numpy arrays with a leading runs axis (DESIGN.md §12).
     """
-    preps, statics, consts, steps = _stack_batch(
+    preps, statics, batch = _stack_batch(
         kernel, problems, nets, cfgs, iters, clock=reductions is not None
     )
-    with jax.profiler.TraceAnnotation(
-        "repro.sweep.transfer", runs=len(preps), bytes=_nbytes(consts + steps)
-    ):
-        args = (
-            tuple(jnp.asarray(c) for c in consts),
-            tuple(jnp.asarray(s) for s in steps),
-        )
+    with _transfer_span(batch.per_run, batch.tables):
+        args = jax.tree.map(jnp.asarray, batch.args)
+    key = _statics_key(statics)
     with jax.profiler.TraceAnnotation("repro.sweep.execute"):
         if reductions is not None:
-            fn = _batch_reduced_fn(kernel, _statics_key(statics), reductions)
+            fn = _batch_reduced_fn(kernel, key, reductions, batch.shared)
             return {k: np.asarray(v) for k, v in fn(*args).items()}
-        x, z, metrics = _batch_fn(kernel, _statics_key(statics))(*args)
+        x, z, metrics = _batch_fn(kernel, key, batch.shared)(*args)
         return _unstack_traces(preps, x, z, metrics)
 
 
@@ -303,7 +406,7 @@ def _sharded_fn(
     kernel: MethodKernel,
     statics_key: tuple,
     D: int,
-    n_consts: int,
+    shared: Tuple[bool, ...],
     n_steps: int,
     donate: bool,
 ):
@@ -318,22 +421,33 @@ def _sharded_fn(
     reason (pallas_call has no replication rule). Nothing in the scan
     crosses the runs axis, so per-run math — and the outputs — are
     bitwise identical to the single-device vmap.
+
+    Takes a `_Stacked` group's arguments: the tables (if any) replicated
+    on every device, the index and the other inputs split on the runs
+    axis. Every chunk reuses the tables, so only the per-run inputs are
+    donated.
     """
     mesh = _runs_mesh()
     assert mesh.devices.shape[0] == D  # cache key consistency
+    runs = P("runs")
     spec = (
-        tuple(P("runs") for _ in range(n_consts)),
-        tuple(P("runs") for _ in range(n_steps)),
+        tuple(runs for s in shared if not s),
+        tuple(runs for _ in range(n_steps)),
     )
-    out_spec = (P("runs"), P("runs"), (P("runs"), P("runs"), P("runs")))
+    if any(shared):
+        spec = (tuple(P() for s in shared if s), runs, *spec)
+    out_spec = (runs, runs, (runs, runs, runs))
     fn = jax.shard_map(
-        jax.vmap(_compose(kernel, statics_key)),
+        _batched(_compose(kernel, statics_key), shared),
         mesh=mesh,
         in_specs=spec,
         out_specs=out_spec,
         check_vma=False,
     )
-    return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+    first = int(any(shared))  # the tables, argument 0, are not donated
+    return jax.jit(
+        fn, donate_argnums=tuple(range(first, len(spec))) if donate else ()
+    )
 
 
 @lru_cache(maxsize=None)
@@ -367,26 +481,31 @@ def _sharded_reduced_fn(
     return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
 
 
-def _bytes_per_run(
-    consts, steps, statics: dict, preps: List[Prepared]
-) -> int:
-    """Estimated per-run device footprint: stacked inputs + scan outputs."""
-    R = len(preps)
-    in_bytes = sum(a.nbytes for a in consts + steps) // max(R, 1)
+def _bytes_per_run(batch: _Stacked, statics: dict) -> Tuple[int, int]:
+    """Estimated device footprint: (bytes per run, bytes per device).
+
+    A run holds its stacked inputs, its rows of the tables as taken on
+    the device, and its scan outputs; every device holds the tables once.
+    """
+    R = len(jax.tree.leaves(batch.per_run)[0])
+    rows = sum(t.nbytes // len(t) for t in batch.tables)
+    in_bytes = _nbytes(batch.per_run) // R + rows
     iters = int(statics.get("iters", 1))
     # x/z outputs mirror the largest const (the data block); metrics are
     # 3 float traces of length iters.
-    out_bytes = 3 * iters * 8
-    for a in consts:
-        out_bytes += a.nbytes // max(R, 1)
-    return max(in_bytes + out_bytes, 1)
+    out_bytes = 3 * iters * 8 + rows + _nbytes(batch.consts) // R
+    return max(in_bytes + out_bytes, 1), _nbytes(batch.tables)
 
 
-def _chunk_runs(R_pad: int, D: int, per_run_bytes: int) -> int:
+def _chunk_runs(
+    R_pad: int, D: int, per_run_bytes: int, shared_bytes: int = 0
+) -> int:
     """Largest run count per dispatch within the per-device budget,
-    a multiple of the device count D (so every chunk shards evenly)."""
+    a multiple of the device count D (so every chunk shards evenly).
+    ``shared_bytes`` sit on every device whatever the chunk."""
     budget = int(os.environ.get(_MEM_BUDGET_ENV, _DEFAULT_MEM_MB)) * 2**20
-    fit = (budget * D) // (2 * per_run_bytes)  # 2x slack for temporaries
+    # 2x slack for temporaries
+    fit = (max(budget - shared_bytes, 0) * D) // (2 * per_run_bytes)
     chunk = max(D, (fit // D) * D)
     return min(chunk, R_pad)
 
@@ -482,7 +601,7 @@ def _run_reduced_chunked(
                 n, D,
             )
         del preps
-        put_c, put_s = _put_sharded(csl, ssl, -(-n // D) * D, mesh, layout)
+        _, (put_c, put_s) = _put_sharded((csl, ssl), mesh, layout)
         del csl, ssl  # the chunk's host copies die before the next one
         with jax.profiler.TraceAnnotation("repro.sweep.execute"):
             out = fn(put_c, put_s)
@@ -492,37 +611,34 @@ def _run_reduced_chunked(
     }
 
 
-def _pad_runs(
-    arrays: Tuple[np.ndarray, ...], n: int, D: int
-) -> Tuple[np.ndarray, ...]:
-    """Pad the n-run axis to a multiple of D by repeating the last run
-    (its outputs are sliced off after the dispatch)."""
+def _pad_runs(tree, n: int, D: int):
+    """Pad the n-run axis of every array in ``tree`` to a multiple of D by
+    repeating the last run (its outputs are sliced off after the
+    dispatch)."""
     pad = -(-n // D) * D - n
     if not pad:
-        return arrays
-    return tuple(
-        np.concatenate([a, np.repeat(a[-1:], pad, axis=0)]) for a in arrays
+        return tree
+    return jax.tree.map(
+        lambda a: np.concatenate([a, np.repeat(a[-1:], pad, axis=0)]), tree
     )
 
 
-def _put_sharded(consts, steps, runs: int, mesh: Mesh, layout: AxisLayout):
-    """Place ``runs`` stacked host runs (padding included) on the runs
-    mesh, in a transfer span.
-
-    PartitionSpec is tuple-like, so zip over the inferred specs rather
-    than tree-mapping across them."""
-    cspec, sspec = batch_specs((consts, steps), layout)
-    with jax.profiler.TraceAnnotation(
-        "repro.sweep.transfer", runs=runs, bytes=_nbytes(consts + steps)
-    ):
+def _put_sharded(
+    per_run, mesh: Mesh, layout: AxisLayout,
+    tables: Tuple[np.ndarray, ...] = (), sharing: bool = False,
+):
+    """Place stacked host runs (padding included) on the runs mesh and
+    ``tables`` replicated on every device, in a transfer span
+    (`_transfer_span`). Returns (tables, per_run) placed."""
+    everywhere = NamedSharding(mesh, P())
+    with _transfer_span(per_run, tables, sharing):
         return (
-            tuple(
-                jax.device_put(a, NamedSharding(mesh, s))
-                for a, s in zip(consts, cspec)
-            ),
-            tuple(
-                jax.device_put(a, NamedSharding(mesh, s))
-                for a, s in zip(steps, sspec)
+            tuple(jax.device_put(t, everywhere) for t in tables),
+            jax.tree.map(
+                lambda a: jax.device_put(
+                    a, NamedSharding(mesh, batch_specs(a, layout))
+                ),
+                per_run,
             ),
         )
 
@@ -580,29 +696,35 @@ def run_sharded(
             kernel, problems, nets, cfgs, iters, reductions
         )
 
-    preps, statics, consts, steps = _stack_batch(
-        kernel, problems, nets, cfgs, iters
+    # Tables are replicated on the D devices, so they ship D times.
+    preps, statics, batch = _stack_batch(
+        kernel, problems, nets, cfgs, iters, copies=D
     )
     R = len(preps)
     mesh = _runs_mesh()
     layout = AxisLayout(mesh, data=("runs",), model="model")
     donate = jax.default_backend() in ("tpu", "gpu")
     fn = _sharded_fn(
-        kernel, _statics_key(statics), D, len(consts), len(steps), donate
+        kernel, _statics_key(statics), D, batch.shared, len(batch.steps),
+        donate,
     )
 
-    chunk = _chunk_runs(
-        -(-R // D) * D, D, _bytes_per_run(consts, steps, statics, preps)
-    )
+    chunk = _chunk_runs(-(-R // D) * D, D, *_bytes_per_run(batch, statics))
+    sharing = batch.index is not None
+    placed = None  # the tables on every device, from the first chunk on
     outs: List[Tuple] = []
     for lo in range(0, R, chunk):
         n = min(chunk, R - lo)
         with jax.profiler.TraceAnnotation("repro.sweep.stack"):
-            csl = _pad_runs(tuple(a[lo : lo + n] for a in consts), n, D)
-            ssl = _pad_runs(tuple(a[lo : lo + n] for a in steps), n, D)
-        put_c, put_s = _put_sharded(csl, ssl, -(-n // D) * D, mesh, layout)
+            part = _pad_runs(
+                jax.tree.map(lambda a: a[lo : lo + n], batch.per_run), n, D
+            )
+        ship = batch.tables if placed is None else ()  # later chunks: none
+        put_t, put_r = _put_sharded(part, mesh, layout, ship, sharing)
+        if placed is None:
+            placed = (put_t,) if sharing else ()
         with jax.profiler.TraceAnnotation("repro.sweep.execute"):
-            x, z, (acc, te, ze) = fn(put_c, put_s)
+            x, z, (acc, te, ze) = fn(*placed, *put_r)
             outs.append(
                 tuple(np.asarray(o)[:n] for o in (x, z, acc, te, ze))
             )

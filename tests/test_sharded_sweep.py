@@ -114,12 +114,17 @@ def test_chunked_execution_matches_unchunked(monkeypatch):
 
 
 def test_chunk_rule_device_aligned(monkeypatch):
-    """Chunk sizes are multiples of D, at least D, at most the padded R."""
+    """Chunk sizes are multiples of D, at least D, at most the padded R.
+    Shared const tables sit on every device whatever the chunk, so they
+    come off the budget before it is split among runs."""
     monkeypatch.setenv("REPRO_SHARD_MEM_MB", "1")
     assert driver._chunk_runs(16, 8, per_run_bytes=10 * 2**20) == 8
     monkeypatch.setenv("REPRO_SHARD_MEM_MB", "4096")
     assert driver._chunk_runs(16, 8, per_run_bytes=10 * 2**20) == 16
     assert driver._chunk_runs(24, 4, per_run_bytes=1) == 24
+    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "64")
+    assert driver._chunk_runs(256, 8, 2**20, shared_bytes=32 * 2**20) == 128
+    assert driver._chunk_runs(256, 8, 2**20, shared_bytes=2**40) == 8
 
 
 def test_single_device_fallback(monkeypatch):

@@ -23,11 +23,14 @@ PHASES = ("repro.sweep.prepare", "repro.sweep.stack", "repro.sweep.transfer",
 RED = Reduction(fields=("accuracy",), budgets=(0.01,), x="sim_time")
 
 
-def _cases(n):
+def _cases(n, share=1):
+    """``n`` runs; each ``share`` consecutive runs hold one seed's problem
+    (they differ in rho)."""
     return [
         Case(method="csI-ADMM", dataset="usps", N=5, K=6, M=36, S=1,
-             scheme="cyclic", iters=20, seed=s)
-        for s in range(n)
+             scheme="cyclic", iters=20, seed=i // share,
+             rho=1.0 + i % share)
+        for i in range(n)
     ]
 
 
@@ -68,20 +71,31 @@ def _check_groups(spans, groups):
     assert all(a[2] <= b[1] for a, b in zip(rest, rest[1:]))
 
 
-def _stacked_nbytes(cases, clock):
-    """Host bytes of the group's stacked inputs, rebuilt from the cases."""
+def _stacked(cases, clock, copies=1):
+    """The group's stacked inputs, rebuilt from the cases with the
+    sweep's caches, so runs of one seed share its problem's arrays."""
     kernel = get_kernel(cases[0].method)
-    mats = [_materialize(c, {}, {}) for c in cases]
-    _, _, consts, steps = driver._stack_batch(
+    nets, probs = {}, {}
+    mats = [_materialize(c, nets, probs) for c in cases]
+    _, _, batch = driver._stack_batch(
         kernel, [m[1] for m in mats], [m[0] for m in mats],
         [kernel.config(c) for c in cases], cases[0].iters, clock=clock,
+        copies=copies,
     )
-    return sum(a.nbytes for a in consts + steps)
+    return batch
 
 
+def _nbytes(arrays):
+    return sum(a.nbytes for a in jax.tree.leaves(arrays))
+
+
+@pytest.mark.parametrize("share,tables", [(1, 3), (2, 2)],
+                         ids=["own-data", "shared-data"])
 @pytest.mark.parametrize("reduced", [True, False], ids=["summaries", "traces"])
-def test_batched_tier_records_one_span_per_phase(tmp_path, reduced):
-    cases = _cases(3)
+def test_batched_tier_records_one_span_per_phase(tmp_path, reduced, share,
+                                                 tables):
+    # share=2: runs 0 and 1 hold one problem, so 2 tables for 3 runs.
+    cases = _cases(3, share)
     res, spans = _record(tmp_path, spec_or_cases=cases, mode="batched",
                          reductions=RED if reduced else None)
     assert res.n_dispatches == 1
@@ -89,9 +103,14 @@ def test_batched_tier_records_one_span_per_phase(tmp_path, reduced):
     by_name = {s[0]: s[3] for s in spans}
     assert by_name["repro.sweep"] == {"runs": 3}
     assert by_name["repro.sweep.prepare"] == {"runs": 3}
+    batch = _stacked(cases, clock=reduced)
     assert by_name["repro.sweep.transfer"] == {
-        "runs": 3, "bytes": _stacked_nbytes(cases, clock=reduced),
+        "runs": 3, "tables": tables, "bytes": _nbytes(batch.args),
     }
+    # Each table ships once: only the per-run inputs grow with the runs.
+    assert [len(t) for t in batch.tables] == (
+        [] if tables == 3 else [tables] * 4
+    )
     for name in ("repro.sweep.materialize", "repro.sweep.stack",
                  "repro.sweep.execute"):
         assert by_name[name] == {}
@@ -113,14 +132,20 @@ def test_sharded_chunks_record_spans_per_chunk(tmp_path, monkeypatch):
     assert all(_inside(s, top) for s in spans[1:])
     assert [s[3]["runs"] for s in chunks if s[0] == "repro.sweep.prepare"] == [8, 1]
     sent = [s[3] for s in chunks if s[0] == "repro.sweep.transfer"]
+    # The lazy tier prepares per chunk and stacks every run's own data.
     assert [st["runs"] for st in sent] == [8, 8]
-    per_run = _stacked_nbytes(cases[:1], clock=True)
+    assert [st["tables"] for st in sent] == [8, 8]
+    one = _stacked(cases[:1], clock=True)
+    per_run = _nbytes((one.consts, one.steps))
     assert [st["bytes"] for st in sent] == [8 * per_run, 8 * per_run]
 
 
-def test_sharded_trace_path_records_spans_per_chunk(tmp_path, monkeypatch):
+@pytest.mark.parametrize("share", [1, 3, 9],
+                         ids=["own-data", "few-sharers", "shared-data"])
+def test_sharded_trace_path_records_spans_per_chunk(tmp_path, monkeypatch,
+                                                    share):
     monkeypatch.setenv("REPRO_SHARD_MEM_MB", "0")
-    cases = _cases(9)
+    cases = _cases(9, share)
     _, spans = _record(tmp_path, spec_or_cases=cases, mode="sharded")
     names = [s[0] for s in spans]
     # prepare and stack of the whole group, then per chunk the padded
@@ -130,8 +155,21 @@ def test_sharded_trace_path_records_spans_per_chunk(tmp_path, monkeypatch):
         "repro.sweep.stack",
     ] + ["repro.sweep.stack", "repro.sweep.transfer", "repro.sweep.execute"] * 2
     sent = [s[3] for s in spans if s[0] == "repro.sweep.transfer"]
-    per_run = _stacked_nbytes(cases[:1], clock=False)
-    assert sent == [{"runs": 8, "bytes": 8 * per_run}] * 2
+    batch = _stacked(cases, clock=False, copies=8)
+    per_run = _nbytes(batch.per_run) // 9
+    if share < 9:
+        # 9 problems, or 3 that replicated on 8 devices would ship 24
+        # rows against 16 padded runs: every run ships its own data.
+        assert batch.index is None
+        assert sent == [{"runs": 8, "tables": 8, "bytes": 8 * per_run}] * 2
+    else:
+        # One problem for 9 runs: the first chunk ships the table (8
+        # copies against 16 rows), which every later chunk reuses.
+        assert sent == [
+            {"runs": 8, "tables": 1,
+             "bytes": _nbytes(batch.tables) + 8 * per_run},
+            {"runs": 8, "tables": 0, "bytes": 8 * per_run},
+        ]
 
 
 def test_serial_tier_records_no_driver_spans(tmp_path):
