@@ -10,10 +10,12 @@ query heads, output projection) and a pre-norm SwiGLU MLP, a final
 RMSNorm and the tied embedding as the output head. It is written in
 straightforward ``jax.numpy`` in float32 with every matrix product at
 ``highest`` precision; the layers run in a ``lax.scan`` with each layer
-recomputed in the backward pass, so that it fits one chip beside the
-consensus state. Departures from the published description: the norm
-scales are stored as offsets from 1 (the weight is ``1 + scale``), and
-the weights are random from the seed (``init``), not the trained ones.
+recomputed in the backward pass, and the output head one row at a time
+(its logits recomputed in the backward pass), so that it fits one chip
+beside the consensus state. Departures from the published description:
+the norm scales are stored as offsets from 1 (the weight is
+``1 + scale``), and the weights are random from the seed (``init``), not
+the trained ones.
 
 The steps follow arXiv 2010.00914 Algorithm 2 as the trainer runs it in
 incremental mode: at step k (from 1) agent ``(k - 1) mod A`` commits,
@@ -158,10 +160,16 @@ def loss(m: dict, params: dict, tokens, labels, weights) -> jax.Array:
         jax.checkpoint(lambda h, lp: (_layer(m, h, lp), None)), h, p["layers"]
     )
     h = _rmsnorm(h, p["final_norm"], m["rms_norm_eps"])
-    logits = jnp.matmul(h, p["embed"].T, precision=HIGHEST)
+    nll = jax.lax.map(jax.checkpoint(lambda row: _row_nll(p["embed"], *row)), (h, labels))
+    return jnp.sum(weights * nll)
+
+
+def _row_nll(embed, h, labels):
+    """One row's mean over positions of -log p(label | prefix)."""
+    logits = jnp.matmul(h, embed.T, precision=HIGHEST)
     logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return jnp.sum(weights * jnp.mean(logz - gold, axis=-1))
+    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+    return jnp.mean(logz - gold)
 
 
 # -- the coded decode ----------------------------------------------------------

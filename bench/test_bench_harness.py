@@ -36,6 +36,30 @@ def test_cell_files_are_found_by_name(cell):
 
 
 @pytest.mark.parametrize("cell", CELLS)
+def test_a_cell_gets_exactly_the_metrics_that_name_it(cell):
+    r = harness.resolve(cell)
+    # A metric without a list of workloads belongs to every cell that
+    # reports the end-to-end metric it moves.
+    named = {m["name"] for m in SPEC["end_to_end"] if cell in m.get("workloads", [cell])}
+    named |= {m["name"] for m in SPEC["per_layer"]
+              if cell in m.get("workloads", [cell] if m["moves"] in named else [])}
+    got = {m["name"] for m in r["end_to_end"] + r["per_layer"]}
+    assert got == named == set(r["readers"])
+    # A sweep cell and a training cell never read each other's metrics.
+    kinds = {n.rsplit(".", 1)[1] for n in got if "." in n}
+    assert len(kinds) <= 1
+    for rate, kind in [("sweep_run_iters_per_s", "sweep"), ("train_tokens_per_s", "train")]:
+        if rate in got:
+            assert kinds == {kind}
+    w = r["cell"]
+    (config,) = [c for c in SPEC["configs"] if c["name"] == w["config"]]
+    files = [ROOT / config["file"], BENCH / "traffic" / f"{w['traffic']}.json",
+             BENCH / "generators" / f"{r['traffic']['generator']}.py"]
+    files += [BENCH / "metrics" / f"{n}.py" for n in got]
+    assert all(f.is_file() for f in files)
+
+
+@pytest.mark.parametrize("cell", CELLS)
 def test_every_per_layer_metric_moves_a_metric_its_cells_report(cell):
     r = harness.resolve(cell)
     reported = {m["name"] for m in r["end_to_end"]}

@@ -7,14 +7,10 @@ and so does a run with the timed path broken underneath: a step that
 returns its state unchanged, half of the batch left out with the mean
 taken over the rest, and the loss altered where the step produces it.
 The cell runs on one chip, so no exchange between chips can be left
-out.
-
-The cell is not in ``BENCHMARK.json`` until its check is proven on the
-chip (``PERF.md``); these tests register it, with the entries it will
-have, in a copy of the file."""
+out. The cell, its configuration and its metrics are read from the
+repository's ``BENCHMARK.json``, as a run on the chip reads them."""
 
 import copy
-import json
 import sys
 import time
 from pathlib import Path
@@ -30,37 +26,12 @@ import harness  # noqa: E402
 
 CELL = "qwen3-0.6b.consensus"
 SEED = 2**31 + 13
-ONLY = {"workloads": [CELL]}
-ENTRIES = {
-    "configs": [{"name": "qwen3-0.6b", "source": "https://huggingface.co/Qwen/Qwen3-0.6B",
-                 "file": "bench/configs/qwen3-0.6b.json", "reduced": ["seq_len", "agents_per_chip"],
-                 "why": "dense decoder with GQA and qk-norm at full width and depth"}],
-    "workloads": [{"name": CELL, "config": "qwen3-0.6b", "traffic": CELL, "chips": 1,
-                   "why": "closed loop: steps of 16 rows x 128 tokens"}],
-    "end_to_end": [dict(ONLY, name="train_tokens_per_s", unit="tokens/s", better="higher",
-                        source="host_clock")],
-    "per_layer": [
-        dict(ONLY, name=name, unit=unit, better=better, source="device_trace", layer=layer,
-             moves="train_tokens_per_s")
-        for name, unit, better, layer in [
-            ("idle_share.train", "%", "lower", "device"),
-            ("device_ms_per_step.train", "ms", "lower", "consensus step"),
-            ("mfu.train", "%", "higher", "consensus step"),
-        ]
-    ],
-}
 
 
 @pytest.fixture(scope="module")
-def root(tmp_path_factory):
-    """A checkout whose ``BENCHMARK.json`` has the cell."""
-    spec = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    for key, entries in ENTRIES.items():
-        spec[key] += entries
-    root = tmp_path_factory.mktemp("checkout")
-    (root / "BENCHMARK.json").write_text(json.dumps(spec))
-    (root / "bench").symlink_to(BENCH)
-    return root
+def root():
+    """The checkout whose ``BENCHMARK.json`` registers the cell."""
+    return harness.ROOT
 
 
 @pytest.fixture(scope="module")
