@@ -32,6 +32,7 @@ _ARCH_MODULES = {
     "qwen3-0.6b": "qwen3_0_6b",
     "recurrentgemma-9b": "recurrentgemma_9b",
     "whisper-medium": "whisper_medium",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCHS = tuple(_ARCH_MODULES)

@@ -223,11 +223,12 @@ class ConsensusRuntime:
             (loss, metrics), grads = jax.value_and_grad(
                 self.model.loss, has_aux=True
             )(x_a, b)
-            return grads, loss, metrics["nll"]
+            counters = {k: v for k, v in metrics.items() if k.startswith("moe/")}
+            return grads, loss, metrics["nll"], counters
 
-        grads, losses, nlls = jax.vmap(agent_loss)(
+        grads, losses, nlls, counters = jax.vmap(agent_loss)(
             state["x"], abatch, w
-        )  # grads: (A, ...) pytree
+        )  # grads: (A, ...) pytree; counters: (A,) each
 
         # eq. (5a): x+ = (tau x + rho z + y - G) / (rho + tau), all agents.
         def x_upd(x, y, z, g):
@@ -302,6 +303,14 @@ class ConsensusRuntime:
             "tau": tau,
             "gamma": gamma,
         }
+        if counters:
+            # An expert layer's routing: over layers and agents, and the
+            # slots of the committing agents alone (their work counts).
+            metrics.update(
+                {k: v.max() if k == "moe/max_rows" else v.sum() for k, v in counters.items()}
+            )
+            metrics["moe/committed_rows"] = jnp.sum(
+                mask.astype(jnp.int32) * counters["moe/held_rows"])
         return new_state, metrics
 
     # -- jit plumbing ----------------------------------------------------------

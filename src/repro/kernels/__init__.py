@@ -7,6 +7,8 @@
   ssd_scan — Mamba-2 chunked state-space-duality scan (mamba2-1.3b).
   rglru_scan — RG-LRU linear recurrence via in-kernel doubling scan
       (recurrentgemma-9b).
+  expert_gmm — grouped matmul over the experts a chip holds, its grid
+      sized by the rows routed (DeepSeek-V3 expert layers, moonlight).
 
 `ops` are the jitted public entry points; `ref` holds the pure-jnp oracles
 the tests sweep against.
@@ -15,6 +17,7 @@ the tests sweep against.
 from .ops import (
     coded_admm_update,
     coded_combine,
+    expert_gmm,
     flash_attention,
     rglru_scan,
     ssd_scan,
@@ -23,6 +26,7 @@ from .ops import (
 __all__ = [
     "coded_combine",
     "coded_admm_update",
+    "expert_gmm",
     "flash_attention",
     "ssd_scan",
     "rglru_scan",

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from .coded_combine import coded_admm_update_kernel, coded_combine_kernel
+from .expert_gmm import grouped_matmul
 from .flash_attention import flash_attention_kernel
 from .rglru_scan import rglru_scan_kernel
 from .ssd_scan import ssd_scan_kernel
@@ -22,6 +23,7 @@ from .ssd_scan import ssd_scan_kernel
 __all__ = [
     "coded_combine",
     "coded_admm_update",
+    "expert_gmm",
     "fit_block_n",
     "flash_attention",
     "ssd_scan",
@@ -47,6 +49,13 @@ def fit_block_n(n: int, block_n: int = 4096, lane: int = 128) -> int:
     Constraints').
     """
     return min(block_n, _pad_to(max(n, 1), lane))
+
+
+def expert_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """Grouped expert product: rows of lhs (m, k) sorted by group, group
+    g's rows times rhs[g] (k, n); rows past the last group read 0.
+    Differentiable (`repro.kernels.expert_gmm`)."""
+    return grouped_matmul(_interpret())(lhs, rhs, group_sizes)
 
 
 # --------------------------------------------------------------------------

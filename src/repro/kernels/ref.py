@@ -18,6 +18,7 @@ __all__ = [
     "flash_attention_ref",
     "ssd_scan_ref",
     "rglru_scan_ref",
+    "expert_gmm_ref",
 ]
 
 
@@ -140,3 +141,18 @@ def rglru_scan_ref(
 
     h, hs = jax.lax.scan(step, h, jnp.arange(S))
     return hs.transpose(1, 0, 2), h
+
+
+def expert_gmm_ref(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """out[r] = lhs[r] @ rhs[g] for the rows r of group g, the rows sorted
+    by group (group g owns the next group_sizes[g] rows); rows past the
+    last group read 0. lhs (m, k), rhs (G, k, n); accumulated in f32,
+    returned in lhs's dtype. Every group's product is taken over all rows
+    and masked: G times the kernel's work, for tests."""
+    ends = jnp.cumsum(group_sizes)
+    gid = jnp.searchsorted(ends, jnp.arange(lhs.shape[0]), side="right")
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    for g in range(rhs.shape[0]):
+        y = jnp.dot(lhs, rhs[g], preferred_element_type=jnp.float32)
+        out = out + jnp.where((gid == g)[:, None], y, 0.0)
+    return out.astype(lhs.dtype)

@@ -32,6 +32,15 @@ class ModelConfig:
     mrope_sections: Optional[Tuple[int, int, int]] = None  # qwen2-vl M-RoPE
     sliding_window: Optional[int] = None  # mixtral SWA / rg local attention
     norm: str = "rmsnorm"  # rmsnorm | layernorm
+    norm_eps: float = 1e-6  # RMSNorm epsilon
+    # multi-head latent attention (DeepSeek-V3 MLA; kv_lora_rank > 0
+    # selects the whole DeepSeek-V3 block, ``is_deepseek_v3``): no q-LoRA;
+    # keys and values come from a normed latent of kv_lora_rank per
+    # position, with one rope key of qk_rope_head_dim shared by every head
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # mlp
     d_ff: int = 0
     mlp_act: str = "swiglu"  # swiglu | geglu | gelu
@@ -45,6 +54,21 @@ class ModelConfig:
     moe_groups: int = 1
     # mesh axis name to anchor the group dim to ("" = let XLA propagate)
     moe_shard_axis: str = ""
+    # DeepSeek-V3 expert layers (``is_deepseek_v3``): the first
+    # ``first_dense_layers`` layers keep a dense MLP of d_ff, the rest route
+    # each token to ``experts_per_token`` of ``n_experts`` experts of width
+    # d_expert by sigmoid score + correction bias, weight them by the
+    # normalised chosen scores times ``routed_scale``, and add
+    # ``n_shared_experts`` shared experts as one SwiGLU of
+    # n_shared_experts * d_expert. Dropless: this chip holds experts
+    # [expert_offset, expert_offset + experts_held) (0 = all) and computes
+    # every token-slot routed to them.
+    d_expert: int = 0
+    n_shared_experts: int = 0
+    first_dense_layers: int = 0
+    routed_scale: float = 1.0
+    experts_held: int = 0
+    expert_offset: int = 0
     # SSM (mamba2 / SSD)
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -75,6 +99,9 @@ class ModelConfig:
     # (repro.kernels; interpret-mode on CPU, native on TPU)
     attn_impl: str = "jnp"
     ssm_impl: str = "jnp"
+    # grouped expert products: "jnp" (kernels.ref twin) or "pallas"
+    # (kernels.expert_gmm)
+    moe_impl: str = "jnp"
 
     # ---- derived ---------------------------------------------------------
 
@@ -87,6 +114,17 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.n_heads, 1)
+
+    @property
+    def is_deepseek_v3(self) -> bool:
+        """kv_lora_rank > 0 selects the whole DeepSeek-V3 block: MLA
+        attention in every layer, ``first_dense_layers`` dense layers, then
+        expert layers of d_expert. No config here takes one part alone."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def q_per_kv(self) -> int:
@@ -116,6 +154,10 @@ class ModelConfig:
             assert self.n_heads % max(self.n_kv_heads, 1) == 0
         if self.family == "moe":
             assert 0 < self.experts_per_token <= self.n_experts
+        if self.is_deepseek_v3:
+            assert self.d_expert > 0 and self.n_layers > self.first_dense_layers
+            assert 0 <= self.expert_offset
+            assert self.expert_offset + self.n_experts_held <= self.n_experts
         if self.family == "ssm":
             assert self.ssm_state > 0 and self.ssm_heads > 0
         if self.family == "hybrid":
@@ -141,6 +183,8 @@ class ModelConfig:
                 + D  # pre-norm
             )
             return n + L * per
+        if self.is_deepseek_v3:
+            return n + self._mla_moe_param_count()
         hd, nh, nkv = self.d_head, self.n_heads, self.n_kv_heads
         attn = D * nh * hd + 2 * D * nkv * hd + nh * hd * D
         if self.qk_norm:
@@ -164,8 +208,32 @@ class ModelConfig:
             return n + enc + dec + self.encoder_positions * D
         return n + L * (attn + mlp + norms) + D
 
+    def _mla_moe_param_count(self) -> int:
+        """Layers and final norm of an MLA model with DeepSeek-V3 expert
+        layers, counting the experts held."""
+        D, H, r = self.d_model, self.n_heads, self.kv_lora_rank
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        attn = (
+            D * H * qk
+            + D * (r + self.qk_rope_head_dim)
+            + r
+            + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+            + H * self.v_head_dim * D
+        )
+        Ld = self.first_dense_layers
+        dense = attn + 3 * D * self.d_ff + 2 * D
+        shared = 3 * D * self.n_shared_experts * self.d_expert
+        expert = attn + shared + D * self.n_experts + self.n_experts
+        expert += self.n_experts_held * 3 * D * self.d_expert + 2 * D
+        return Ld * dense + (self.n_layers - Ld) * expert + D
+
     def active_param_count(self) -> int:
         """Active params per token (MoE: only top-k experts)."""
+        if self.is_deepseek_v3:
+            routed = self.n_experts_held * 3 * self.d_model * self.d_expert
+            active = self.experts_per_token * 3 * self.d_model * self.d_expert
+            L = self.n_layers - self.first_dense_layers
+            return self.param_count() - L * (routed - active)
         if self.family != "moe":
             return self.param_count()
         D, F, L = self.d_model, self.d_ff, self.n_layers

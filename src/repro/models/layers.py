@@ -72,11 +72,21 @@ def apply_rope(
     theta: float,
     fraction: float = 1.0,
     mrope_sections: Optional[Tuple[int, int, int]] = None,
+    interleaved: bool = False,
 ) -> jax.Array:
+    """Rotate pairs of the leading ``fraction`` of each head by position.
+
+    By default the first and second halves of the rotated slice are the
+    two coordinates of each pair. ``interleaved`` pairs dimensions (2i,
+    2i+1) instead and returns the rotated slice in halves order, as
+    DeepSeek-V3's modeling code does (it de-interleaves, then rotates
+    halves)."""
     hd = x.shape[-1]
     rot = int(hd * fraction)
     rot -= rot % 2
     x_rot, x_pass = x[..., :rot], x[..., rot:]
+    if interleaved:
+        x_rot = jnp.concatenate([x_rot[..., 0::2], x_rot[..., 1::2]], axis=-1)
 
     if mrope_sections is not None:
         # Qwen2-VL M-RoPE: the rot/2 frequency slots are split into three
@@ -120,12 +130,13 @@ def _expand_kv(k: jax.Array, q_per_kv: int) -> jax.Array:
 def naive_attention(
     q: jax.Array,  # (B, Sq, H, hd)
     k: jax.Array,  # (B, Skv, H, hd)  (already GQA-expanded)
-    v: jax.Array,
+    v: jax.Array,  # (B, Skv, H, vd)  (vd may differ from hd: MLA)
     causal: bool,
     window: Optional[int] = None,
     q_offset: int = 0,
 ) -> jax.Array:
-    """Reference full-matrix attention (used for short sequences + oracles)."""
+    """Reference full-matrix attention (used for short sequences + oracles).
+    Scores are scaled by 1/sqrt(hd); the output has v's head width."""
     B, Sq, H, hd = q.shape
     Skv = k.shape[1]
     scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
@@ -148,7 +159,7 @@ def naive_attention(
 def blocked_attention(
     q: jax.Array,  # (B, S, H, hd)
     k: jax.Array,  # (B, S, H, hd)
-    v: jax.Array,
+    v: jax.Array,  # (B, S, H, vd)  (vd may differ from hd: MLA)
     causal: bool = True,
     window: Optional[int] = None,
     block_q: int = 512,
@@ -162,13 +173,14 @@ def blocked_attention(
     the Pallas kernel skips them; XLA's scan keeps memory bounded either way.
     """
     B, S, H, hd = q.shape
+    vd = v.shape[-1]
     assert S % block_q == 0 and S % block_kv == 0, (S, block_q, block_kv)
     nq, nk = S // block_q, S // block_kv
     scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
 
     qb = q.reshape(B, nq, block_q, H, hd).transpose(1, 0, 3, 2, 4)
     kb = k.reshape(B, nk, block_kv, H, hd).transpose(1, 0, 3, 2, 4)
-    vb = v.reshape(B, nk, block_kv, H, hd).transpose(1, 0, 3, 2, 4)
+    vb = v.reshape(B, nk, block_kv, H, vd).transpose(1, 0, 3, 2, 4)
 
     def per_qblock(qi, qblk):  # qblk (B, H, bq, hd)
         q32 = qblk.astype(jnp.float32) * scale
@@ -196,26 +208,26 @@ def blocked_attention(
             )
             return (acc_new, m_new, l_new), None
 
-        acc0 = jnp.zeros((B, H, block_q, hd), jnp.float32)
+        acc0 = jnp.zeros((B, H, block_q, vd), jnp.float32)
         m0 = jnp.full((B, H, block_q), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, H, block_q), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(
             kv_step, (acc0, m0, l0), (jnp.arange(nk), kb, vb)
         )
         out = acc / jnp.maximum(l[..., None], 1e-30)
-        return out  # (B, H, bq, hd)
+        return out  # (B, H, bq, vd)
 
     out = jax.lax.map(
         lambda args: per_qblock(*args), (jnp.arange(nq), qb)
-    )  # (nq, B, H, bq, hd)
-    out = out.transpose(1, 0, 3, 2, 4).reshape(B, S, H, hd)
+    )  # (nq, B, H, bq, vd)
+    out = out.transpose(1, 0, 3, 2, 4).reshape(B, S, H, vd)
     return out.astype(q.dtype)
 
 
 def decode_attention(
     q: jax.Array,  # (B, 1, H, hd)
     k_cache: jax.Array,  # (B, C, KV, hd) — C = cache length (maybe ring)
-    v_cache: jax.Array,
+    v_cache: jax.Array,  # (B, C, KV, vd)
     valid: jax.Array,  # (B, C) bool — which cache slots participate
 ) -> jax.Array:
     """Single-token decode attention over a (possibly ring-buffered) cache.
@@ -242,7 +254,7 @@ def decode_attention(
         v_cache,
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(B, 1, H, hd).astype(q.dtype)
+    return out.reshape(B, 1, H, v_cache.shape[-1]).astype(q.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -369,6 +381,90 @@ def moe_apply(
     out = jax.vmap(comb)(slot_out * w)  # (G, Tg, D)
     out = wsc(out, ax, None, None)
     return out.reshape(T, D).astype(x.dtype), aux.astype(jnp.float32)
+
+
+def moe_share_apply(
+    x: jax.Array,  # (T, D) flattened tokens
+    p: dict,  # router (D, E), router_bias (E,), w_gate/w_up (Eh, D, F),
+    # w_down (Eh, F, D), shared_gate/shared_up (D, Fs), shared_down (Fs, D)
+    n_experts: int,
+    top_k: int,
+    expert_offset: int,
+    routed_scale: float,
+    impl: str = "jnp",
+) -> Tuple[jax.Array, dict]:
+    """A DeepSeek-V3 expert layer's share on a chip holding experts
+    [expert_offset, expert_offset + Eh) of ``n_experts``, dropless.
+
+    Routing is over all experts: sigmoid scores s, the top ``top_k`` of
+    s + router_bias chosen (the bias only chooses, so its gradient is 0),
+    weights s_chosen / sum(s_chosen) * routed_scale. The
+    token-slots (T * top_k) are sorted by expert; those of held experts
+    come first, one group per held expert, in a buffer sized for the worst
+    case (every slot held), so no slot is ever dropped. The grouped SwiGLU
+    products (``impl``: "pallas" for `repro.kernels.expert_gmm`, "jnp" for
+    its twin) compute the held groups only; each slot's result goes back
+    to its token times its weight, and the shared expert is added. What
+    the experts held elsewhere would add is not computed.
+
+    Returns (out (T, D), counters): token-slots routed to the held
+    experts (``held_rows``), the most on one held expert (``max_rows``),
+    and held slots left uncomputed (``dropped``: 0, since the buffer
+    holds every slot).
+    DESIGN.md §18.
+    """
+    T, D = x.shape
+    Eh = p["w_gate"].shape[0]
+    if impl == "pallas":
+        from repro.kernels.ops import expert_gmm as gmm
+    else:
+        from repro.kernels.ref import expert_gmm_ref as gmm
+
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), p["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        s = jax.nn.sigmoid(logits)  # (T, E)
+        _, idx = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, idx, axis=1)
+        w = w / jnp.sum(w, axis=1, keepdims=True) * routed_scale
+
+    with jax.named_scope("moe.dispatch"):
+        e = idx.reshape(-1) - expert_offset  # (T*k,) slot -> local expert
+        held = (e >= 0) & (e < Eh)
+        key = jnp.where(held, e, Eh).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True)  # held slots first, by expert
+        group_sizes = jnp.zeros((Eh + 1,), jnp.int32).at[key].add(1)[:Eh]
+        rows = x[order // top_k]  # (T*k, D)
+
+    with jax.named_scope("moe.experts"):
+        g = gmm(rows, p["w_gate"], group_sizes)
+        u = gmm(rows, p["w_up"], group_sizes)
+        y = gmm(jax.nn.silu(g) * u, p["w_down"], group_sizes)  # (T*k, D)
+
+    with jax.named_scope("moe.combine"):
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        slot_out = y[inverse].reshape(T, top_k, D)  # held slots; others 0
+        out = jnp.einsum(
+            "tkd,tk->td", slot_out, w.astype(slot_out.dtype),
+            preferred_element_type=jnp.float32,
+        )
+
+    with jax.named_scope("moe.shared"):
+        shared = mlp_apply(
+            x, {"w_gate": p["shared_gate"], "w_up": p["shared_up"],
+                "w_down": p["shared_down"]}, "swiglu",
+        )
+
+    counters = {
+        "held_rows": jnp.sum(held.astype(jnp.int32)),
+        "max_rows": jnp.max(group_sizes),
+        # The buffer holds all T * top_k slots: none can be left out.
+        "dropped": jnp.zeros((), jnp.int32),
+    }
+    return out.astype(x.dtype) + shared, counters
 
 
 def _z(like: jax.Array) -> jax.Array:

@@ -97,6 +97,7 @@ def test_full_config_is_exact_assignment(arch):
         "qwen3-0.6b": (28, 1024, 16, 8, 3072, 151936),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
         "whisper-medium": (24, 1024, 16, 16, 4096, 51865),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.vocab)
     assert got == expected
@@ -115,6 +116,12 @@ def test_full_config_is_exact_assignment(arch):
         assert cfg.mrope_sections is not None
     if arch == "whisper-medium":
         assert cfg.encoder_layers == 24
+    if arch == "moonlight-16b-a3b":
+        assert (cfg.n_experts, cfg.experts_per_token) == (64, 6)
+        assert (cfg.d_expert, cfg.n_shared_experts, cfg.first_dense_layers) == (1408, 2, 1)
+        assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim) == (512, 128, 64, 128)
+        assert cfg.n_experts_held == 64 and cfg.routed_scale == 2.446
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "mixtral-8x22b"])

@@ -112,3 +112,22 @@ def test_kernel_compiles_at_large_n(native, one_chip, op):
     """J = 16 over a 2**20 vector in 16384-lane tiles (~1.3 MB of VMEM)."""
     fn = functools.partial(OPS[op], block_n=16_384)
     _assert_native(fn, _shapes(one_chip, op, 16, 2**20, True))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "vjp"])
+def test_expert_gmm_compiles_at_moonlight_widths(native, one_chip, grad):
+    """The grouped expert products of one agent's expert layer in the
+    moonlight cell: 8,192 tokens x top 6 slots in a worst-case buffer,
+    8 held experts of 2,048 -> 1,408 (bfloat16); with the VJP, the data
+    gradient (weights transposed) and the weight gradient too."""
+    lhs = jax.ShapeDtypeStruct((49152, 2048), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((8, 2048, 1408), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    fn = ops.expert_gmm
+    if grad:
+        fn = jax.value_and_grad(
+            lambda a, b, g: jnp.sum(ops.expert_gmm(a, b, g).astype(jnp.float32)), (0, 1))
+    text = jax.jit(fn).lower(lhs, rhs, sizes).compile().as_text()
+    assert text.count("tpu_custom_call") == (3 if grad else 1)
+    names = ("expert_gmm", "expert_gmm_t", "expert_gmm_tgmm") if grad else ("expert_gmm",)
+    assert all(f"%{name}." in text for name in names)
