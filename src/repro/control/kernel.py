@@ -297,7 +297,7 @@ class AdaptiveADMM(IncrementalADMM):
                 aux["bpar"], n_arms,
             ),
         )
-        # The pulled arm's sub-batch size mu: re-derive the gather mask
+        # The pulled arm's sub-batch size mu: re-derive the window mask
         # and normalization the base setup fixed from the scalar bound.
         mu_k = aux["mu_arms"][arm]
         aux = dict(

@@ -1,9 +1,9 @@
 """Incremental (c)sI-/I-ADMM as a MethodKernel (paper Algorithms 1 & 2).
 
 The ONE step implementation for the whole ADMM family (DESIGN.md §8): the
-zero-weight-masked, flat-gather scan body that previously existed twice
-(a serial `dynamic_slice` variant and a masked batched clone) is now the
-canonical kernel, executed serially or vmapped by `repro.methods.driver`.
+zero-weight-masked scan body that previously existed twice (a serial
+`dynamic_slice` variant and a masked batched clone) is now the canonical
+kernel, executed serially or vmapped by `repro.methods.driver`.
 
 Per step (active agent i = i_k, eqs. 5a/5b/4c):
 
@@ -52,6 +52,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -62,7 +63,13 @@ from repro.core.problems import LeastSquaresProblem
 from repro.core.timing import TimingModel
 from repro.kernels.ops import coded_admm_update, fit_block_n
 
-from .base import MethodKernel, Prepared, register
+from .base import (
+    MethodKernel,
+    Prepared,
+    TableRow,
+    as_table_row,
+    register,
+)
 
 __all__ = ["ADMMRun", "IncrementalADMM", "ADMM_KERNEL"]
 
@@ -86,6 +93,7 @@ class IncrementalADMM(MethodKernel):
     the pre-refactor family key."""
 
     name = "admm"
+    row_consts = (0, 1)  # O and T: each step reads K windows of the table
 
     # -- host side ---------------------------------------------------------
 
@@ -219,8 +227,10 @@ class IncrementalADMM(MethodKernel):
 
     def setup(self, consts, statics):
         O, T, x_star, O_test, T_test, rho, mu = consts
-        N, b, p = O.shape
-        d = T.shape[2]
+        O, T = as_table_row(O), as_table_row(T)
+        _, N, b, p = O.table.shape
+        d = T.table.shape[3]
+        dt = O.table.dtype
         MU = statics["MU"]
         rows = jnp.arange(MU)
         aux = dict(
@@ -232,28 +242,28 @@ class IncrementalADMM(MethodKernel):
             Ct=O_test.T @ T_test,
             TTt=jnp.sum(T_test * T_test),
             n_test=O_test.shape[0],
-            # Flat views: per-step mini-batches gather the K*MU needed rows
-            # straight out of the (N*b, p) pool instead of copying the
-            # active agent's whole (b, p) block.
-            O_flat=O.reshape(N * b, p),
-            T_flat=T.reshape(N * b, d),
+            # O and T side by side as one pool of the table's rows
+            # (`_pool`): each step fetches its K partitions' sub-batches
+            # as K windows of MU consecutive rows, one start per window.
+            pool=_pool(O, T, MU),
             rows=rows,
-            valid=(rows < mu).astype(O.dtype),
-            inv_mu=1.0 / mu.astype(O.dtype),
-            part=jnp.arange(statics["K"]),
+            valid=(rows < mu).astype(dt),
+            inv_mu=1.0 / mu.astype(dt),
+            part=jnp.arange(statics["K"], dtype=jnp.int32),
             rho=rho,
             b=b,
             shape=(N, p, d),
-            dtype=O.dtype,
+            dtype=dt,
             # Static tile for the fused Pallas x-update (lane-legal, no
             # gross padding of the flat (p*d,) parameter vector).
             block_n=fit_block_n(p * d),
         )
         if statics["exact_x"]:
             # I-ADMM exact solve operands: (O^T O / b + rho I), O^T T / b.
+            O, T = O.table[O.row], T.table[T.row]
             aux["H"] = jnp.einsum("nbp,nbq->npq", O, O) / b
             aux["rhs0"] = jnp.einsum("nbp,nbd->npd", O, T) / b
-            aux["eye"] = jnp.eye(p, dtype=O.dtype)
+            aux["eye"] = jnp.eye(p, dtype=dt)
         return aux
 
     def init(self, aux, statics):
@@ -279,17 +289,10 @@ class IncrementalADMM(MethodKernel):
                 aux["H"][i] + rho * aux["eye"], aux["rhs0"][i] + rho * z + yi
             )
         else:
-            # One gather of all K partitions' sub-batches; rows >= mu carry
-            # weight exactly 0 (their clamped OOB gathers contribute exact
+            # The K partitions' sub-batches, one window of MU rows each;
+            # rows >= mu carry weight exactly 0 (they contribute exact
             # zeros to the gradient sums — batched == serial elementwise).
-            idx = (
-                i * aux["b"]
-                + aux["part"][:, None] * statics["P"]
-                + off
-                + aux["rows"][None, :]
-            )
-            Ob = aux["O_flat"][idx]  # (K, MU, p)
-            Tb = aux["T_flat"][idx]  # (K, MU, d)
+            Ob, Tb = _minibatch(aux, i, off, statics)
             # Per-ECN coded message: the masked sub-batch gradient g~_j
             # (eq. 6 before decode), one row of the fused kernel's msgs.
             r = (aux["valid"] * aux["inv_mu"])[None, :, None] * (Ob @ xi - Tb)
@@ -375,6 +378,73 @@ class IncrementalADMM(MethodKernel):
             # Flush in-flight increments: the run ends, updates land.
             z = z + state["pend"].sum(axis=0)
         return state["x"], z
+
+
+# Lanes of a TPU vector register: the pool's rows lie along them.
+_LANES = 128
+
+
+def _tiles(size: int) -> int:
+    """Aligned lane tiles that hold any window of ``size`` rows."""
+    return -(-size // _LANES) + 1
+
+
+def _pool(O: TableRow, T: TableRow, pad: int) -> TableRow:
+    """The (U, N, b, p) and (U, N, b, d) tables O and T as one pool of
+    their N*b rows laid along lanes, (U, p + d, tiles, 128): row r of the
+    pool is [:, :, r // 128, r % 128], and its rows from N*b on repeat
+    the last row, as far as a window of ``pad`` rows can reach.
+
+    A run with mu < MU reads up to MU - mu rows past its partition, and
+    its last agent's last window up to MU - mu rows past the data. The
+    repeated rows keep every window inside the pool, so no window start
+    is clamped (which would shift the rows that carry weight), and a row
+    past the data is the last row, as a clamped row index reads it.
+    """
+    U, N, b, _ = O.table.shape
+    n = N * b
+    tiles = (n + pad) // _LANES + _tiles(pad)
+
+    def lanes(table):
+        flat = table.reshape(U, n, -1).transpose(0, 2, 1)
+        flat = jnp.pad(flat, ((0, 0), (0, 0), (0, tiles * _LANES - n)), mode="edge")
+        return flat.reshape(U, -1, tiles, _LANES)
+
+    pool = jnp.concatenate([lanes(O.table), lanes(T.table)], axis=1)
+    return TableRow(pool, O.row)
+
+
+def _windows(pool: TableRow, starts, size: int):
+    """(len(starts), size, c): for each start s, the rows [s, s + size) of
+    the run's row of ``pool`` (`_pool`), read from the table itself.
+
+    Each window is fetched as the aligned lane tiles that hold it, whole
+    (a gather of contiguous tiles, not of single rows), then moved to
+    lane 0 by a barrel shifter: log2(128) fixed rotations, each taken or
+    not by one bit of s % 128. Rows are only moved, never combined."""
+    table, row = pool
+    c, lanes = table.shape[1], table.shape[3]
+    shape = (1, c, _tiles(size), lanes)
+    row, zero = jnp.asarray(row, starts.dtype), jnp.zeros((), starts.dtype)
+
+    def window(s):
+        w = jax.lax.dynamic_slice(table, (row, zero, s // lanes, zero), shape)
+        w = w.reshape(c, -1)
+        for bit in range(lanes.bit_length() - 1):
+            taken = ((s % lanes) >> bit) & 1 == 1
+            w = jnp.where(taken, jnp.roll(w, -(1 << bit), axis=1), w)
+        return w[:, :size].T
+
+    return jax.vmap(window)(starts)
+
+
+def _minibatch(aux, i, off, statics):
+    """(Ob, Tb), (K, MU, p) and (K, MU, d): agent ``i``'s K partitions'
+    sub-batches at offset ``off``, one window of MU rows each."""
+    starts = i * aux["b"] + aux["part"] * statics["P"] + off
+    rows = _windows(aux["pool"], starts, statics["MU"])
+    p = aux["shape"][1]
+    return rows[..., :p], rows[..., p:]
 
 
 ADMM_KERNEL = register(IncrementalADMM(), "sI-ADMM", "csI-ADMM", "I-ADMM")

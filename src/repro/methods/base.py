@@ -24,7 +24,7 @@ semantics-preserving transform (asserted elementwise in
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -34,6 +34,8 @@ from repro.core.problems import LeastSquaresProblem
 
 __all__ = [
     "Prepared",
+    "TableRow",
+    "as_table_row",
     "MethodKernel",
     "KERNELS",
     "register",
@@ -54,7 +56,7 @@ class Prepared:
       statics: hashable jit statics; must be identical across a batch
         (shapes, K, exact_x, iters, ...).
       max_statics: statics the batched driver reconciles with ``max()``
-        across runs (e.g. the masked gather bound MU) — the corresponding
+        across runs (e.g. the masked window bound MU) — the corresponding
         runtime value lives in ``consts`` so runs with different values
         still share one trace (DESIGN.md §7).
       comm: cumulative communication units per iteration, host accounting.
@@ -67,6 +69,26 @@ class Prepared:
     max_statics: Dict[str, int]
     comm: np.ndarray
     sim_time: np.ndarray
+
+
+class TableRow(NamedTuple):
+    """One run's row of a const table that the runs of a batch share: the
+    whole ``table`` (U, ...) and the run's ``row`` of it. The batched
+    driver hands a shared const in this form, in place of the run's own
+    copy, at the positions a kernel lists in `MethodKernel.row_consts`:
+    the step then reads the table itself, and no per-run copy is taken
+    before the scan."""
+
+    table: Any
+    row: Any
+
+
+def as_table_row(const) -> TableRow:
+    """``const`` as a `TableRow`: itself, or a run's own array as the one
+    row of a table."""
+    if isinstance(const, TableRow):
+        return const
+    return TableRow(const[None], 0)
 
 
 class MethodKernel:
@@ -89,6 +111,10 @@ class MethodKernel:
     """
 
     name: str = "?"
+    # Positions of `Prepared.consts` that `setup` takes as `TableRow`s
+    # where the runs of a batch share all of them, else as the runs' own
+    # arrays (`as_table_row` reads both).
+    row_consts: Tuple[int, ...] = ()
 
     def config(self, case):
         raise NotImplementedError

@@ -34,7 +34,7 @@ from repro.core.graph import Network
 from repro.core.problems import LeastSquaresProblem
 from repro.distributed.sharding import AxisLayout, batch_specs
 
-from .base import MethodKernel, Prepared
+from .base import MethodKernel, Prepared, TableRow
 from .reductions import Reduction
 
 __all__ = ["run_serial", "run_batch", "run_sharded"]
@@ -111,21 +111,35 @@ def _clock_steps(prep: Prepared) -> np.ndarray:
     )
 
 
-def _batched(run, shared: Tuple[bool, ...]):
+def _batched(run, shared: Tuple[bool, ...], rows: Tuple[int, ...] = ()):
     """vmap of a composed run over a `_Stacked` group's arguments.
 
     Each run's consts tuple is rebuilt on the device: at the ``shared``
     positions its row ``index`` of the table, elsewhere its own stacked
-    const. The rows are taken once, before the scan. A group that shares
-    nothing takes the plain vmap: (consts, steps), no tables, no index."""
-    vrun = jax.vmap(run)
+    const. The rows are taken once, before the scan, except at the
+    positions in ``rows`` (the kernel's `MethodKernel.row_consts`): there
+    the run gets a `TableRow`, the whole table and its index, and reads
+    the table where it needs it. A group that shares nothing takes the
+    plain vmap: (consts, steps), no tables, no index."""
     if not any(shared):
-        return vrun
+        return jax.vmap(run)
+    if not all(shared[pos] for pos in rows):
+        rows = ()  # a kernel gets its row_consts all in one form
 
     def fn(tables, index, consts, steps):
         t, c = iter(tables), iter(consts)
-        full = tuple(next(t)[index] if s else next(c) for s in shared)
-        return vrun(full, steps)
+        full, axes = [], []
+        for pos, s in enumerate(shared):
+            if not s:
+                full.append(next(c))
+                axes.append(0)
+            elif pos in rows:
+                full.append(TableRow(next(t), index))
+                axes.append(TableRow(None, 0))
+            else:
+                full.append(next(t)[index])
+                axes.append(0)
+        return jax.vmap(run, in_axes=(tuple(axes), 0))(tuple(full), steps)
 
     return fn
 
@@ -139,7 +153,9 @@ def _serial_fn(kernel: MethodKernel, statics_key: tuple):
 def _batch_fn(
     kernel: MethodKernel, statics_key: tuple, shared: Tuple[bool, ...]
 ):
-    return jax.jit(_batched(_compose(kernel, statics_key), shared))
+    return jax.jit(
+        _batched(_compose(kernel, statics_key), shared, kernel.row_consts)
+    )
 
 
 @lru_cache(maxsize=None)
@@ -157,7 +173,10 @@ def _batch_reduced_fn(
     shared: Tuple[bool, ...],
 ):
     return jax.jit(
-        _batched(_compose_reduced(kernel, statics_key, spec), shared)
+        _batched(
+            _compose_reduced(kernel, statics_key, spec), shared,
+            kernel.row_consts,
+        )
     )
 
 
@@ -277,7 +296,7 @@ def _stack_batch(
     """Prepare R runs and stack them on a leading runs axis (host-side).
 
     All runs must share the kernel's static signature; ``max_statics``
-    (e.g. the masked gather bound MU) are reconciled with ``max`` so runs
+    (e.g. the masked window bound MU) are reconciled with ``max`` so runs
     whose *runtime* value differs (mixed straggler tolerance S in a fig5
     grid) still share the trace. Raises ValueError on mixed statics —
     `repro.experiments.sweep.run_sweep` groups by signature first. With
@@ -438,7 +457,7 @@ def _sharded_fn(
         spec = (tuple(P() for s in shared if s), runs, *spec)
     out_spec = (runs, runs, (runs, runs, runs))
     fn = jax.shard_map(
-        _batched(_compose(kernel, statics_key), shared),
+        _batched(_compose(kernel, statics_key), shared, kernel.row_consts),
         mesh=mesh,
         in_specs=spec,
         out_specs=out_spec,

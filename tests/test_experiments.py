@@ -28,13 +28,13 @@ from repro.experiments.sweep import _signature
 ITERS = 60
 
 
-def _fig5_style_spec(runs=2, S_values=(0, 1, 2)):
+def _fig5_style_spec(runs=2, S_values=(0, 1, 2), iters=ITERS):
     """K=6 grid like fig5, shrunk (usps standin, M=36) for test time."""
     return SweepSpec(
         "fig5_smoke",
         Case(
             method="csI-ADMM", dataset="usps", N=5, K=6, M=36,
-            scheme="cyclic", iters=ITERS,
+            scheme="cyclic", iters=iters,
         ),
         axes={"S": list(S_values), "seed": list(range(runs))},
         fixup=lambda c: dataclasses.replace(
@@ -61,9 +61,35 @@ def test_grid_expansion_and_dedupe():
     assert len(spec2.cases()) == 1
 
 
-def test_vmapped_matches_serial_elementwise():
-    """Same seeds -> same traces, vmapped vs the per-run seed entry point."""
-    spec = _fig5_style_spec(runs=2)
+def _windows_past_the_data(case) -> bool:
+    """Whether the run, batched under its group's MU = M / K (an S = 0
+    run's), fetches a window that runs past the end of the data: its last
+    agent's last partition, at an offset within MU - mu of the end."""
+    from repro.methods import get_kernel
+
+    kernel = get_kernel(case.method)
+    net = make_network(case.N, case.connectivity, seed=case.seed)
+    prob = allocate(DATASETS[case.dataset](case.seed), case.N, case.K)
+    prep = kernel.prepare(prob, net, kernel.config(case), case.iters)
+    agents, offsets = prep.steps[0], prep.steps[1]
+    last = (case.K - 1) * prep.statics["P"] + offsets + case.M // case.K
+    return bool(np.any((agents == case.N - 1) & (last > prob.b)))
+
+
+@pytest.mark.parametrize(
+    "iters", [ITERS, 80], ids=["fig5", "pool_end"]
+)
+def test_vmapped_matches_serial_elementwise(iters):
+    """Same seeds -> same traces, vmapped vs the per-run seed entry point.
+
+    The group mixes S, so runs with mu < MU fetch windows that reach past
+    their partitions; at 80 iterations every such run's last agent reads
+    past the end of the data too."""
+    spec = _fig5_style_spec(runs=2, iters=iters)
+    if iters > ITERS:
+        assert all(
+            _windows_past_the_data(c) for c in spec.cases() if c.S > 0
+        )
     batched = run_sweep(spec)
     serial = run_sweep(spec, serial=True)
     assert batched.cases == serial.cases

@@ -10,6 +10,7 @@ executable. conftest.py forces 8 CPU devices and float64.
 """
 
 import copy
+import dataclasses
 
 import jax
 import numpy as np
@@ -62,19 +63,28 @@ def _grid(seeds, configs):
 
 @pytest.mark.parametrize("reduced", [False, True], ids=["traces", "summaries"])
 @pytest.mark.parametrize(
-    "configs,shared",
-    [(CONFIGS, DATA), (CONFIGS[:1], (False,) * 7)],
-    ids=["tables", "one-run-per-problem"],
+    "configs,shared,own_T",
+    [
+        (CONFIGS, DATA, False),
+        (CONFIGS[:1], (False,) * 7, False),
+        (CONFIGS, (True, False) + DATA[2:], True),
+    ],
+    ids=["tables", "one-run-per-problem", "T-per-run"],
 )
-def test_batched_equals_serial(configs, shared, reduced):
+def test_batched_equals_serial(configs, shared, own_T, reduced):
     """3 seeds: with 4 configs a seed, the data ships as 3-row tables;
-    with one, every position stacks per run. Both equal the serial tier."""
+    with one, every position stacks per run. With a copy of T per run, O
+    ships as a table and T per run, so the kernel, which reads O and T
+    by rows of their tables, takes each run's own. All equal the serial
+    tier."""
     kernel, probs, nets, cfgs = _grid(range(3), configs)
+    if own_T:
+        probs = [dataclasses.replace(p, T=p.T.copy()) for p in probs]
     R = len(probs)
     _, _, batch = driver._stack_batch(kernel, probs, nets, cfgs, ITERS)
     assert batch.shared == shared
     if any(shared):
-        assert [len(t) for t in batch.tables] == [3] * 4
+        assert [len(t) for t in batch.tables] == [3] * sum(shared)
         np.testing.assert_array_equal(batch.index, np.repeat(range(3), 4))
     else:
         assert batch.tables == () and batch.index is None

@@ -17,14 +17,20 @@ a described chip cannot be read back from it.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.graph import make_network
+from repro.core.problems import DATASETS, allocate
+from repro.experiments import Case
 from repro.kernels import ops
+from repro.methods import Reduction, driver, get_kernel
 
 UPDATE = ops.coded_admm_update.__wrapped__
 COMBINE = ops.coded_combine.__wrapped__
@@ -131,3 +137,83 @@ def test_expert_gmm_compiles_at_moonlight_widths(native, one_chip, grad):
     assert text.count("tpu_custom_call") == (3 if grad else 1)
     names = ("expert_gmm", "expert_gmm_t", "expert_gmm_tgmm") if grad else ("expert_gmm",)
     assert all(f"%{name}." in text for name in names)
+
+
+# An HLO value's name and dimensions; a gather's or a dynamic slice's
+# operand and slice sizes.
+_VALUE = re.compile(r"%([\w.-]+) = \w+\[([\d,]*)\]")
+_READ = re.compile(
+    r"(?:gather|dynamic-slice)\(%([\w.-]+)[,)].*?slice_sizes=\{([\d,]*)\}"
+)
+
+
+def _pool_reads(text, rows):
+    """Consecutive rows taken by each gather or dynamic slice of a pool of
+    at least ``rows`` rows: along an axis of that many rows or more, or
+    along a (tiles, 128) pair of axes that lays that many rows on lanes."""
+    dims = {
+        m[1]: [int(n) for n in m[2].split(",") if n]
+        for m in _VALUE.finditer(text)
+    }
+    reads = []
+    for m in _READ.finditer(text):
+        shape, sizes = dims[m[1]], [int(n) for n in m[2].split(",")]
+        for ax, n in enumerate(shape):
+            if n >= rows and n != 128:
+                reads.append(sizes[ax])
+            elif n == 128 and ax and shape[ax - 1] * 128 >= rows:
+                reads.append(sizes[ax - 1] * sizes[ax])
+    return reads
+
+
+def _f32(a, sharding):
+    dt = {np.float64: jnp.float32, np.int64: jnp.int32}.get(
+        np.asarray(a).dtype.type, np.asarray(a).dtype
+    )
+    return jax.ShapeDtypeStruct(np.shape(a), dt, sharding=sharding)
+
+
+@pytest.mark.parametrize(
+    "dataset, N, K, M",
+    [("synthetic", 10, 6, 360), ("usps", 10, 3, 90)],
+    ids=["fleet_synth_p3", "usps_pd640"],
+)
+def test_batched_step_fetches_windows_not_rows(
+    native, one_chip, dataset, N, K, M
+):
+    """The batched csI-ADMM step with a streaming reduction, as the sweep
+    engine dispatches it: 4 runs of mixed S (1 and 2, so MU is the S = 1
+    bound) over 2 shared datasets, float32. At the fleet cell's shape
+    (N = 10, b = 5,040, p = 3, K = 6, MU = 30) and at the USPS width
+    (p*d = 640, K = 3), every read of the data pool takes a window of at
+    least MU rows: none fetches a single row."""
+    kernel = get_kernel("csI-ADMM")
+    probs, nets, cfgs = [], [], []
+    for seed in (0, 1):
+        prob = allocate(DATASETS[dataset](seed), N, K)
+        net = make_network(N, 0.5, seed=seed)
+        for S in (1, 2):
+            case = Case(
+                method="csI-ADMM", dataset=dataset, N=N, K=K, M=M,
+                scheme="cyclic", S=S, seed=seed, iters=8,
+            )
+            probs.append(prob)
+            nets.append(net)
+            cfgs.append(kernel.config(case))
+    _, statics, batch = driver._stack_batch(
+        kernel, probs, nets, cfgs, 8, clock=True
+    )
+    assert batch.index is not None  # the datasets ship as shared tables
+    spec = Reduction(fields=("accuracy", "test_error"), budgets=(0.5,))
+    fn = driver._batched(
+        driver._compose_reduced(kernel, driver._statics_key(statics), spec),
+        batch.shared,
+        kernel.row_consts,
+    )
+    args = jax.tree.map(lambda a: _f32(a, one_chip), batch.args)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    MU = statics["MU"]
+    reads = _pool_reads(text, N * probs[0].b)
+    assert reads, "no read of the data pool found in the program"
+    assert min(reads) >= MU, f"rows read {sorted(set(reads))} (MU = {MU})"
