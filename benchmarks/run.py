@@ -29,7 +29,7 @@ from .common import Rows, peak_rss_mb
 MODULES = ("fig3", "fig4", "fig5", "kernels")
 
 
-def run_sweeps(names, rows: Rows, iters=None, runs=None, mode=None) -> dict:
+def run_sweeps(names, rows: Rows, iters=None, runs=None, mode="auto") -> dict:
     """Run named sweeps; returns {sweep_name: engine summary} for --json."""
     import dataclasses
 
@@ -129,7 +129,7 @@ def main(argv=None) -> int:
         ap.error("--serial contradicts --mode " + args.mode)
     if args.json and not args.sweep:
         ap.error("--json requires --sweep (engine summaries)")
-    mode = "serial" if args.serial else args.mode
+    mode = "serial" if args.serial else args.mode or "auto"
 
     if args.list_sweeps:
         from repro.experiments import SWEEPS, get_sweep

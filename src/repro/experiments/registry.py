@@ -404,8 +404,9 @@ def fleet_frontier(iters: int = 1000, runs: int = 1000) -> SweepSpec:
     O(iters x runs) memory. The declared `Reduction` keeps everything
     the frontier needs — accuracy/test-error at sim-time budgets,
     time-to-accuracy targets, trajectory quantiles — in O(grid) memory,
-    so the default grid (12,000 runs) executes in a handful of sharded
-    dispatches under REPRO_SHARD_MEM_MB (EXPERIMENTS.md 'Fleet scale').
+    so the default grid (12,000 runs) executes in a handful of chunked
+    dispatches within the driver's per-device memory budget, on one
+    executable (EXPERIMENTS.md 'Fleet scale').
     Lognormal vs Pareto base responses (finite vs infinite variance)
     with a planted 4x speed class, against cyclic/MDS exact decoding and
     the deadline-truncated approximate family (DESIGN.md §11).

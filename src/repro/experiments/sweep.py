@@ -6,13 +6,15 @@ plus named axes; its Cartesian expansion is the grid. `run_sweep` groups
 the grid by jit *static signature* (everything that would force a fresh
 trace: shapes, K, P, exact_x, iters, method kernel — see
 `MethodKernel.static_signature`, DESIGN.md §8) and executes each group
-as one `jax.vmap`-ed `lax.scan` — one compile and one device dispatch per
-group, however many (seed, config) pairs it contains. With more than one
-visible device the vmapped runs axis is additionally laid out over a
-1-D mesh (`repro.methods.driver.run_sharded`, DESIGN.md §9); the tier is
-picked by ``mode`` ("auto"/"serial"/"batched"/"sharded"). Host-side
-sampling (topology, data allocation, straggler times, decode vectors)
-stays per-run and is stacked into the batched scan's per-step inputs.
+as one `jax.vmap`-ed `lax.scan` — one compile per group, however many
+(seed, config) pairs it contains, and one device dispatch per chunk of
+the per-device memory budget (one unless the group outgrows it). With
+more than one visible device the vmapped runs axis is additionally laid
+out over a 1-D mesh (`repro.methods.driver.run_sharded`, DESIGN.md §9);
+the tier is picked by ``mode`` ("auto"/"serial"/"batched"/"sharded").
+Host-side sampling (topology, data allocation, straggler times, decode
+vectors) stays per-run and is stacked into the batched scan's per-step
+inputs.
 Each call names its host phases (materialize, prepare, stack, transfer,
 execute) with profiler spans, which exist only while a profile is being
 captured (DESIGN.md §16).
@@ -24,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import os
 import time
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -292,17 +293,9 @@ def _dispatch_group(
     return run_batch(kernel, probs, nets, cfgs, iters, reductions=reductions)
 
 
-def _resolve_mode(serial: bool, mode: Optional[str]) -> str:
-    """Execution-tier resolution (DESIGN.md §9): explicit ``mode`` wins,
-    the legacy ``serial`` flag maps onto it, REPRO_SWEEP_MODE sets the
-    process default, and ``auto`` picks sharded iff >1 device is visible.
-    """
-    if mode is None:
-        mode = "serial" if serial else os.environ.get(
-            "REPRO_SWEEP_MODE", "auto"
-        )
-    elif serial and mode != "serial":
-        raise ValueError(f"serial=True contradicts mode={mode!r}")
+def _resolve_mode(mode: str) -> str:
+    """Execution-tier resolution (DESIGN.md §9): ``auto`` picks sharded
+    iff >1 device is visible."""
     if mode not in MODES:
         raise ValueError(f"unknown sweep mode {mode!r}; known: {MODES}")
     if mode == "auto":
@@ -313,8 +306,7 @@ def _resolve_mode(serial: bool, mode: Optional[str]) -> str:
 def run_sweep(
     spec_or_cases,
     *,
-    serial: bool = False,
-    mode: Optional[str] = None,
+    mode: str = "auto",
     verbose: bool = False,
     reductions: Optional[Reduction] = None,
 ) -> SweepResult:
@@ -322,13 +314,12 @@ def run_sweep(
 
     Args:
       spec_or_cases: a `SweepSpec` or an explicit list of `Case`s.
-      serial: run each case through the per-run (seed) entry points instead
-        of the batched ones — the reference path for correctness tests and
-        the "before" column of the EXPERIMENTS.md §Perf timing table.
-      mode: execution tier — "serial", "batched" (single-device vmap),
-        "sharded" (the same vmap laid out over a device mesh on the runs
-        axis, DESIGN.md §9), or "auto" (sharded iff >1 device is visible;
-        the default, overridable via REPRO_SWEEP_MODE).
+      mode: execution tier — "serial" (each case through the per-run
+        entry points: the reference path for correctness tests and the
+        "before" column of the EXPERIMENTS.md §Perf timing table),
+        "batched" (single-device vmap), "sharded" (the same vmap laid out
+        over a device mesh on the runs axis, DESIGN.md §9), or "auto"
+        (the default: sharded iff >1 device is visible).
       verbose: print one line per dispatched group.
       reductions: a `Reduction` to fold in-scan instead of materializing
         Traces (DESIGN.md §12); defaults to the spec's own ``reductions``
@@ -347,7 +338,7 @@ def run_sweep(
     )
     if not cases:
         raise ValueError("empty sweep")
-    mode = _resolve_mode(serial, mode)
+    mode = _resolve_mode(mode)
     enable_compilation_cache()
 
     with jax.profiler.TraceAnnotation("repro.sweep", runs=len(cases)):

@@ -206,8 +206,7 @@ class IncrementalADMM(MethodKernel):
         self, problem: LeastSquaresProblem, run: ADMMRun, iters: int
     ) -> dict:
         # Exact: make_schedule's mu IS M_bar // K (no sampling involved),
-        # so chunked streaming execution shares one jit trace with the
-        # eager batched path.
+        # so every chunk of a group shares one jit trace.
         return dict(MU=run.cfg.M_bar // run.cfg.K)
 
     def _statics(self, run: ADMMRun, problem, iters, sched) -> dict:

@@ -8,7 +8,8 @@ baselines (W-ADMM, D-ADMM, DGD, EXTRA), and the beyond-paper variants
 
 - serial:  ``lax.scan(step)`` over iterations, one run per dispatch;
 - batched: ``vmap`` of the *same* scan over a leading runs axis, one jit
-  trace and one device dispatch per static-signature group;
+  trace per static-signature group, one device dispatch per memory
+  chunk of it;
 - sharded: ``shard_map`` of the batched scan over a 1-D device mesh on
   the runs axis — each device executes its local runs, bitwise equal to
   batched (DESIGN.md §9).
@@ -55,10 +56,11 @@ class Prepared:
         methods whose iterations consume no per-step data (gossip).
       statics: hashable jit statics; must be identical across a batch
         (shapes, K, exact_x, iters, ...).
-      max_statics: statics the batched driver reconciles with ``max()``
-        across runs (e.g. the masked window bound MU) — the corresponding
-        runtime value lives in ``consts`` so runs with different values
-        still share one trace (DESIGN.md §7).
+      max_statics: statics the batched driver takes as the ``max()`` of
+        :meth:`MethodKernel.max_statics_bound` across runs (e.g. the
+        masked window bound MU) — the corresponding runtime value lives
+        in ``consts`` so runs with different values still share one
+        trace (DESIGN.md §7, §8).
       comm: cumulative communication units per iteration, host accounting.
       sim_time: cumulative simulated seconds per iteration.
     """
@@ -148,14 +150,15 @@ class MethodKernel:
     ) -> Dict[str, int]:
         """Exact bound on :attr:`Prepared.max_statics` WITHOUT preparing.
 
-        The streaming-reduction sharded path (DESIGN.md §12) prepares runs
-        lazily per memory chunk, so the global jit statics must be known
-        up front from (problem, cfg) alone — ``prepare()`` would cost the
-        very O(R x iters) host memory the path exists to avoid. Kernels
-        whose ``prepare`` emits ``max_statics`` must override this with a
-        value >= every run's prepared value (equal keys); the driver
-        verifies each chunk against it. Kernels with empty ``max_statics``
-        inherit this default.
+        The batched driver prepares runs per memory chunk (DESIGN.md §9),
+        so the group's jit statics must be known up front from (problem,
+        cfg) alone — preparing every run first would cost the very
+        O(R x iters) host memory chunking exists to avoid. Kernels whose
+        ``prepare`` emits ``max_statics`` must override this with a value
+        >= every run's prepared value (equal keys); the driver verifies
+        each prepared run against it, and compiles for the bound, so an
+        exact bound compiles what the runs need. Kernels with empty
+        ``max_statics`` inherit this default.
         """
         return {}
 

@@ -4,25 +4,26 @@
 ``run_batch`` executes R runs as ``vmap`` of the *same* composed scan —
 the batched engine is a pure performance transform of the serial path
 because both call literally the same step function. ``run_sharded`` lays
-the batched runs axis of that same vmapped scan out over a
-`jax.sharding.Mesh` of every visible device (``shard_map`` over a 1-D
-runs mesh, NamedSharding-placed inputs, buffer donation on accelerator
-backends, automatic chunking when a grid exceeds the per-device memory
-budget), falling back structurally to the single-device vmap when only
-one device is visible (DESIGN.md §9). A fourth backend, the TPU mesh runtime
+the runs axis of that same vmapped scan out over a `jax.sharding.Mesh`
+of every visible device (``shard_map`` over a 1-D runs mesh), falling
+back structurally to the single-device vmap when only one device is
+visible (DESIGN.md §9). Both run one pipeline, `_run_chunks`: prepare,
+stack, place and execute, chunk by chunk within a per-device memory
+budget. A fourth backend, the TPU mesh runtime
 (`repro.distributed.consensus`, DESIGN.md §3), shares the algorithmic
 core but owns its sharding-aware state layout.
 
 Jitted executables are cached per kernel, statics and (batched tiers)
-const-table layout (`_Stacked`), on top of the persistent XLA
+const-table layout and device count, on top of the persistent XLA
 compilation cache enabled by `repro.experiments.sweep`.
 """
 
 from __future__ import annotations
 
-import os
+import dataclasses
+import itertools
 from functools import lru_cache
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.admm import Trace
 from repro.core.graph import Network
 from repro.core.problems import LeastSquaresProblem
-from repro.distributed.sharding import AxisLayout, batch_specs
 
 from .base import MethodKernel, Prepared, TableRow
 from .reductions import Reduction
@@ -151,11 +151,13 @@ def _serial_fn(kernel: MethodKernel, statics_key: tuple):
 
 @lru_cache(maxsize=None)
 def _batch_fn(
-    kernel: MethodKernel, statics_key: tuple, shared: Tuple[bool, ...]
+    kernel: MethodKernel,
+    statics_key: tuple,
+    shared: Tuple[bool, ...],
+    D: int,
+    donate: bool,
 ):
-    return jax.jit(
-        _batched(_compose(kernel, statics_key), shared, kernel.row_consts)
-    )
+    return _build(_compose(kernel, statics_key), kernel, shared, D, donate)
 
 
 @lru_cache(maxsize=None)
@@ -171,12 +173,44 @@ def _batch_reduced_fn(
     statics_key: tuple,
     spec: Reduction,
     shared: Tuple[bool, ...],
+    D: int,
+    donate: bool,
 ):
+    return _build(
+        _compose_reduced(kernel, statics_key, spec), kernel, shared, D,
+        donate,
+    )
+
+
+def _build(run, kernel: MethodKernel, shared, D: int, donate: bool):
+    """The runs-axis executable of a composed run: jit of its vmap over a
+    `_Stacked` chunk's arguments (`_batched`), on one device for D == 1.
+
+    For D > 1, jit(shard_map(...)) over the runs axis of the 1-D mesh —
+    shard_map, not bare NamedSharding propagation, because the step's
+    Pallas `coded_admm_update` has no SPMD partitioning rule: under
+    GSPMD, XLA walls the op off and reshards its operands every scan
+    iteration (measured ~50x slower); under shard_map each device runs
+    the whole vmapped scan on its local R/D runs and the Pallas call
+    never sees a partitioned operand. check_vma=False for the same
+    reason (pallas_call has no replication rule). Nothing in the scan
+    crosses the runs axis, so per-run math is the single-device vmap's.
+    The tables (if any) are replicated on every device, the index and
+    the other inputs split on the runs axis; chunks reuse the tables, so
+    only the per-run inputs are donated.
+    """
+    fn = _batched(run, shared, kernel.row_consts)
+    if D == 1:
+        return jax.jit(fn)
+    runs = P("runs")
+    specs = (P(), runs, runs, runs) if any(shared) else (runs, runs)
+    fn = jax.shard_map(
+        fn, mesh=_runs_mesh(), in_specs=specs, out_specs=runs,
+        check_vma=False,
+    )
+    first = int(any(shared))  # the tables, argument 0, are not donated
     return jax.jit(
-        _batched(
-            _compose_reduced(kernel, statics_key, spec), shared,
-            kernel.row_consts,
-        )
+        fn, donate_argnums=tuple(range(first, len(specs))) if donate else ()
     )
 
 
@@ -223,7 +257,7 @@ def run_serial(
 
 
 class _Stacked(NamedTuple):
-    """A dispatch group's host inputs, the runs on the leading axis.
+    """A chunk's host inputs, the runs on the leading axis.
 
     A const that several runs hold as the same host object (the grid
     points of one seed share its `LeastSquaresProblem`) ships once, as a
@@ -241,16 +275,9 @@ class _Stacked(NamedTuple):
 
     @property
     def per_run(self) -> tuple:
-        """The arguments with a runs axis: what chunks slice and pad."""
+        """The arguments with a runs axis: what a chunk pads."""
         own = (self.consts, self.steps)
         return own if self.index is None else (self.index, *own)
-
-    @property
-    def args(self) -> tuple:
-        """The batched executables' arguments, in their order."""
-        if self.index is None:
-            return self.per_run
-        return (self.tables, *self.per_run)
 
 
 def _stack_consts(per_run: Sequence[Sequence], copies: int = 1):
@@ -284,61 +311,6 @@ def _stack_consts(per_run: Sequence[Sequence], copies: int = 1):
     return shared, tables, index, consts
 
 
-def _stack_batch(
-    kernel: MethodKernel,
-    problems: Sequence[LeastSquaresProblem],
-    nets: Sequence[Network],
-    cfgs: Sequence,
-    iters: int,
-    clock: bool = False,
-    copies: int = 1,
-) -> Tuple[List[Prepared], dict, _Stacked]:
-    """Prepare R runs and stack them on a leading runs axis (host-side).
-
-    All runs must share the kernel's static signature; ``max_statics``
-    (e.g. the masked window bound MU) are reconciled with ``max`` so runs
-    whose *runtime* value differs (mixed straggler tolerance S in a fig5
-    grid) still share the trace. Raises ValueError on mixed statics —
-    `repro.experiments.sweep.run_sweep` groups by signature first. With
-    ``clock`` the stacked `_clock_steps` ride along as the last step input
-    (the streaming tier's layout, `_compose_reduced`). Consts that runs
-    share by identity become tables shipped ``copies`` times
-    (`_stack_consts`).
-    """
-    R = len(problems)
-    if not (len(nets) == len(cfgs) == R):
-        raise ValueError("problems, nets, cfgs must have equal length")
-    sigs = {
-        kernel.static_signature(p, c, iters)
-        for p, c in zip(problems, cfgs)
-    }
-    if len(sigs) != 1:
-        raise ValueError(
-            f"batch mixes {len(sigs)} static signatures; group runs by "
-            f"{kernel.name} static_signature() first"
-        )
-
-    with jax.profiler.TraceAnnotation("repro.sweep.prepare", runs=R):
-        preps = [
-            kernel.prepare(p, n, c, iters)
-            for p, n, c in zip(problems, nets, cfgs)
-        ]
-    statics = dict(preps[0].statics)
-    if any(pr.statics != statics for pr in preps[1:]):
-        raise ValueError("equal signatures produced unequal statics")
-    for key in preps[0].max_statics:
-        statics[key] = max(pr.max_statics[key] for pr in preps)
-
-    with jax.profiler.TraceAnnotation("repro.sweep.stack"):
-        shared, tables, index, consts = _stack_consts(
-            [pr.consts for pr in preps], copies
-        )
-        steps = _stack([pr.steps for pr in preps])
-        if clock:
-            steps += (np.stack([_clock_steps(pr) for pr in preps]),)
-    return preps, statics, _Stacked(shared, tables, index, consts, steps)
-
-
 def _stack(per_run: Sequence[Sequence]) -> Tuple[np.ndarray, ...]:
     """Stack each position of the runs' input tuples on a runs axis."""
     return tuple(
@@ -347,210 +319,32 @@ def _stack(per_run: Sequence[Sequence]) -> Tuple[np.ndarray, ...]:
     )
 
 
-def _nbytes(tree) -> int:
-    """Host bytes handed to the device: the transfer span's ``bytes``."""
-    return sum(a.nbytes for a in jax.tree.leaves(tree))
-
-
-def _transfer_span(per_run, tables=(), sharing: bool = False):
-    """The transfer span of a dispatch's host inputs: ``runs``, the rows
-    of ``per_run``; ``tables``, the rows of ``tables`` shipped (each run
-    ships its own data where its group is not ``sharing``; a later chunk
-    of one that is ships none); ``bytes``, the host bytes of both."""
-    runs = len(jax.tree.leaves(per_run)[0])
-    n = len(tables[0]) if tables else 0 if sharing else runs
-    return jax.profiler.TraceAnnotation(
-        "repro.sweep.transfer", runs=runs, tables=n,
-        bytes=_nbytes((tables, per_run)),
-    )
-
-
-def _unstack_traces(preps: List[Prepared], x, z, metrics) -> List[Trace]:
-    acc, test_err, z_err = metrics
-    out = [np.asarray(o) for o in (x, z, acc, test_err, z_err)]
-    return [
-        _to_trace(pr, out[0][r], out[1][r], (out[2][r], out[3][r], out[4][r]))
-        for r, pr in enumerate(preps)
-    ]
-
-
-def run_batch(
-    kernel: MethodKernel,
-    problems: Sequence[LeastSquaresProblem],
-    nets: Sequence[Network],
-    cfgs: Sequence,
-    iters: int,
-    reductions: Optional[Reduction] = None,
-):
-    """R runs as ONE vmapped scan — one jit trace, one device dispatch.
-
-    Returns per-run `Trace`s, or — with ``reductions`` — one dict of
-    numpy arrays with a leading runs axis (DESIGN.md §12).
-    """
-    preps, statics, batch = _stack_batch(
-        kernel, problems, nets, cfgs, iters, clock=reductions is not None
-    )
-    with _transfer_span(batch.per_run, batch.tables):
-        args = jax.tree.map(jnp.asarray, batch.args)
-    key = _statics_key(statics)
-    with jax.profiler.TraceAnnotation("repro.sweep.execute"):
-        if reductions is not None:
-            fn = _batch_reduced_fn(kernel, key, reductions, batch.shared)
-            return {k: np.asarray(v) for k, v in fn(*args).items()}
-        x, z, metrics = _batch_fn(kernel, key, batch.shared)(*args)
-        return _unstack_traces(preps, x, z, metrics)
-
-
 # --------------------------------------------------------------------------
-# Mesh-sharded batch execution (DESIGN.md §9)
+# The batched pipeline: prepare -> stack -> place -> execute, per chunk
 # --------------------------------------------------------------------------
 
-# Per-device working-set budget for one sharded dispatch, in MiB. The
-# chunking rule is deliberately coarse (inputs + outputs + one 2x slack
-# factor for XLA temporaries); it only needs to keep a huge grid from
-# OOMing a device, not to model the allocator.
-_MEM_BUDGET_ENV = "REPRO_SHARD_MEM_MB"
-_DEFAULT_MEM_MB = 4096
+# Per-device working-set budget of one dispatch, in bytes. The chunking
+# rule is deliberately coarse (a run's prepared inputs and metric traces,
+# with 2x slack for XLA temporaries); it only needs to keep a huge grid
+# from running a device out of memory, not to model the allocator.
+_MEM_BUDGET = 4096 * 2**20
 
 
 def _runs_mesh() -> Mesh:
-    """1-D device mesh over the runs axis (trailing size-1 model axis so
-    `repro.distributed.sharding.AxisLayout` spec inference applies)."""
-    devs = np.array(jax.devices()).reshape(-1, 1)
-    return Mesh(devs, ("runs", "model"))
+    """1-D mesh of every visible device over the runs axis."""
+    return Mesh(np.array(jax.devices()), ("runs",))
 
 
-@lru_cache(maxsize=None)
-def _sharded_fn(
-    kernel: MethodKernel,
-    statics_key: tuple,
-    D: int,
-    shared: Tuple[bool, ...],
-    n_steps: int,
-    donate: bool,
-):
-    """jit(shard_map(vmap(compose))) over the runs axis of a 1-D mesh.
-
-    shard_map (not bare NamedSharding propagation) because the step's
-    Pallas `coded_admm_update` has no SPMD partitioning rule: under
-    GSPMD, XLA walls the op off and reshards its operands every scan
-    iteration (measured ~50x slower); under shard_map each device runs
-    the whole vmapped scan on its local R/D runs and the Pallas call
-    never sees a partitioned operand. check_vma=False for the same
-    reason (pallas_call has no replication rule). Nothing in the scan
-    crosses the runs axis, so per-run math — and the outputs — are
-    bitwise identical to the single-device vmap.
-
-    Takes a `_Stacked` group's arguments: the tables (if any) replicated
-    on every device, the index and the other inputs split on the runs
-    axis. Every chunk reuses the tables, so only the per-run inputs are
-    donated.
-    """
-    mesh = _runs_mesh()
-    assert mesh.devices.shape[0] == D  # cache key consistency
-    runs = P("runs")
-    spec = (
-        tuple(runs for s in shared if not s),
-        tuple(runs for _ in range(n_steps)),
-    )
-    if any(shared):
-        spec = (tuple(P() for s in shared if s), runs, *spec)
-    out_spec = (runs, runs, (runs, runs, runs))
-    fn = jax.shard_map(
-        _batched(_compose(kernel, statics_key), shared, kernel.row_consts),
-        mesh=mesh,
-        in_specs=spec,
-        out_specs=out_spec,
-        check_vma=False,
-    )
-    first = int(any(shared))  # the tables, argument 0, are not donated
-    return jax.jit(
-        fn, donate_argnums=tuple(range(first, len(spec))) if donate else ()
-    )
-
-
-@lru_cache(maxsize=None)
-def _sharded_reduced_fn(
-    kernel: MethodKernel,
-    statics_key: tuple,
-    spec: Reduction,
-    D: int,
-    n_consts: int,
-    n_steps: int,
-    donate: bool,
-):
-    """jit(shard_map(vmap(compose_reduced))) — the streaming sharded tier.
-
-    Same mesh/shard_map rationale as `_sharded_fn`; the single bare
-    ``P("runs")`` out_spec applies as a prefix to every leaf of the
-    summary dict (each leaf has a leading vmapped runs axis)."""
-    mesh = _runs_mesh()
-    assert mesh.devices.shape[0] == D
-    in_spec = (
-        tuple(P("runs") for _ in range(n_consts)),
-        tuple(P("runs") for _ in range(n_steps + 1)),  # +1: clock steps
-    )
-    fn = jax.shard_map(
-        jax.vmap(_compose_reduced(kernel, statics_key, spec)),
-        mesh=mesh,
-        in_specs=in_spec,
-        out_specs=P("runs"),
-        check_vma=False,
-    )
-    return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
-
-
-def _bytes_per_run(batch: _Stacked, statics: dict) -> Tuple[int, int]:
-    """Estimated device footprint: (bytes per run, bytes per device).
-
-    A run holds its stacked inputs, its rows of the tables as taken on
-    the device, and its scan outputs; every device holds the tables once.
-    """
-    R = len(jax.tree.leaves(batch.per_run)[0])
-    rows = sum(t.nbytes // len(t) for t in batch.tables)
-    in_bytes = _nbytes(batch.per_run) // R + rows
-    iters = int(statics.get("iters", 1))
-    # x/z outputs mirror the largest const (the data block); metrics are
-    # 3 float traces of length iters.
-    out_bytes = 3 * iters * 8 + rows + _nbytes(batch.consts) // R
-    return max(in_bytes + out_bytes, 1), _nbytes(batch.tables)
-
-
-def _chunk_runs(
-    R_pad: int, D: int, per_run_bytes: int, shared_bytes: int = 0
-) -> int:
-    """Largest run count per dispatch within the per-device budget,
-    a multiple of the device count D (so every chunk shards evenly).
-    ``shared_bytes`` sit on every device whatever the chunk."""
-    budget = int(os.environ.get(_MEM_BUDGET_ENV, _DEFAULT_MEM_MB)) * 2**20
-    # 2x slack for temporaries
-    fit = (max(budget - shared_bytes, 0) * D) // (2 * per_run_bytes)
-    chunk = max(D, (fit // D) * D)
-    return min(chunk, R_pad)
-
-
-def _run_reduced_chunked(
+def _statics_bound(
     kernel: MethodKernel,
     problems: Sequence[LeastSquaresProblem],
-    nets: Sequence[Network],
     cfgs: Sequence,
     iters: int,
-    spec: Reduction,
-) -> Dict[str, np.ndarray]:
-    """Streaming sharded execution with LAZY per-chunk prepare (§12).
-
-    The eager path prepares and stacks all R runs before dispatching —
-    host memory O(R x iters) even though the device outputs are O(R).
-    Here runs are prepared only when their chunk dispatches, so peak host
-    memory is O(chunk x iters) + O(R x spec): the chunk size shrinks as
-    per-run schedules grow (`_chunk_runs` on the prepared bytes of run
-    0), which is what keeps fleet-scale RSS flat in ``iters``
-    (EXPERIMENTS.md 'Fleet scale'). Requires the kernel's
-    `max_statics_bound` to be exact enough that every chunk reconciles
-    under ONE set of jit statics — one trace, one executable, chunk
-    count dispatches.
-    """
-    D = len(jax.devices())
+) -> Dict[str, int]:
+    """The group's `MethodKernel.max_statics_bound`: the max over its runs,
+    known before any run is prepared. Raises ValueError on mixed static
+    signatures — `repro.experiments.sweep.run_sweep` groups by signature
+    first."""
     sigs = {
         kernel.static_signature(p, c, iters)
         for p, c in zip(problems, cfgs)
@@ -564,70 +358,65 @@ def _run_reduced_chunked(
     for p, c in zip(problems, cfgs):
         for key, val in kernel.max_statics_bound(p, c, iters).items():
             bound[key] = max(bound.get(key, 0), int(val))
+    return bound
 
-    # One probe prepare: fixes the shared statics and sizes the chunks.
-    with jax.profiler.TraceAnnotation("repro.sweep.prepare", runs=1):
-        prep0 = kernel.prepare(problems[0], nets[0], cfgs[0], iters)
-    if set(prep0.max_statics) != set(bound):
-        raise ValueError(
-            f"{kernel.name}.max_statics_bound() keys {sorted(bound)} != "
-            f"prepared max_statics keys {sorted(prep0.max_statics)}; "
-            "implement the bound hook for chunked streaming execution"
-        )
-    statics = {**prep0.statics, **bound}
-    per_run = (
-        sum(np.asarray(a).nbytes for a in prep0.consts + prep0.steps)
-        + _clock_steps(prep0).nbytes
-    )
-    R = len(problems)
-    mesh = _runs_mesh()
-    layout = AxisLayout(mesh, data=("runs",), model="model")
-    donate = jax.default_backend() in ("tpu", "gpu")
-    fn = _sharded_reduced_fn(
-        kernel, _statics_key(statics), spec, D,
-        len(prep0.consts), len(prep0.steps), donate,
-    )
-    del prep0  # the probe's schedules are re-prepared with its chunk
 
-    chunk = _chunk_runs(-(-R // D) * D, D, max(per_run, 1))
-    outs: List[Dict[str, np.ndarray]] = []
-    for lo in range(0, R, chunk):
-        hi = min(lo + chunk, R)
-        with jax.profiler.TraceAnnotation("repro.sweep.prepare", runs=hi - lo):
-            preps = [
-                kernel.prepare(p, n, c, iters)
-                for p, n, c in zip(
-                    problems[lo:hi], nets[lo:hi], cfgs[lo:hi]
-                )
-            ]
-        for pr in preps:
-            if pr.statics != _shared_statics(statics, pr):
-                raise ValueError(
-                    "equal signatures produced unequal statics"
-                )
-            for key, val in pr.max_statics.items():
-                if int(val) > statics[key]:
-                    raise ValueError(
-                        f"{kernel.name}.max_statics_bound() under-bounds "
-                        f"{key}: prepared {val} > bound {statics[key]}"
-                    )
-        n = hi - lo
-        with jax.profiler.TraceAnnotation("repro.sweep.stack"):
-            csl = _pad_runs(_stack([pr.consts for pr in preps]), n, D)
-            ssl = _pad_runs(
-                _stack([pr.steps for pr in preps])
-                + (np.stack([_clock_steps(pr) for pr in preps]),),
-                n, D,
+def _check_statics(
+    kernel: MethodKernel, preps: Sequence[Prepared], base: dict, bound: dict
+) -> None:
+    """Every run shares the first run's statics, and the bound covers its
+    ``max_statics`` (same keys, no value above the bound)."""
+    for pr in preps:
+        if set(pr.max_statics) != set(bound):
+            raise ValueError(
+                f"{kernel.name}.max_statics_bound() keys {sorted(bound)} != "
+                f"prepared max_statics keys {sorted(pr.max_statics)}; "
+                "implement the bound hook"
             )
-        del preps
-        _, (put_c, put_s) = _put_sharded((csl, ssl), mesh, layout)
-        del csl, ssl  # the chunk's host copies die before the next one
-        with jax.profiler.TraceAnnotation("repro.sweep.execute"):
-            out = fn(put_c, put_s)
-            outs.append({k: np.asarray(v)[:n] for k, v in out.items()})
-    return {
-        k: np.concatenate([o[k] for o in outs]) for k in outs[0]
-    }
+        for key, val in pr.max_statics.items():
+            if int(val) > bound[key]:
+                raise ValueError(
+                    f"{kernel.name}.max_statics_bound() under-bounds "
+                    f"{key}: prepared {val} > bound {bound[key]}"
+                )
+        if pr.statics != base:
+            raise ValueError("equal signatures produced unequal statics")
+
+
+def _stack_runs(
+    preps: Sequence[Prepared], clock: bool, copies: int
+) -> _Stacked:
+    """Stack prepared runs on a leading runs axis (host-side). Consts that
+    runs share by identity become tables shipped ``copies`` times
+    (`_stack_consts`); with ``clock`` the `_clock_steps` ride along as the
+    last step input (the streaming layout, `_compose_reduced`)."""
+    shared, tables, index, consts = _stack_consts(
+        [pr.consts for pr in preps], copies
+    )
+    steps = _stack([pr.steps for pr in preps])
+    if clock:
+        steps += (np.stack([_clock_steps(pr) for pr in preps]),)
+    return _Stacked(shared, tables, index, consts, steps)
+
+
+def _run_bytes(prep: Prepared, clock: bool) -> int:
+    """A run's device footprint for chunking: its prepared inputs as if it
+    shipped all its own data (it takes its rows of any table on the
+    device), plus its three metric traces where no reduction folds them.
+    Tables ship only where they hold fewer rows than a device's runs, so
+    they fit in the same count."""
+    inputs = sum(np.asarray(a).nbytes for a in prep.consts + prep.steps)
+    if clock:
+        return inputs + _clock_steps(prep).nbytes
+    return inputs + 3 * 8 * len(prep.sim_time)
+
+
+def _chunk_runs(R: int, D: int, run_bytes: int) -> int:
+    """Runs per dispatch: as many as the per-device budget holds with 2x
+    slack for temporaries, a multiple of the device count D (so every
+    chunk shards evenly), and no more than R padded to a multiple of D."""
+    fit = _MEM_BUDGET * D // (2 * max(run_bytes, 1))
+    return min(max(D, fit // D * D), -(-R // D) * D)
 
 
 def _pad_runs(tree, n: int, D: int):
@@ -642,32 +431,139 @@ def _pad_runs(tree, n: int, D: int):
     )
 
 
-def _put_sharded(
-    per_run, mesh: Mesh, layout: AxisLayout,
-    tables: Tuple[np.ndarray, ...] = (), sharing: bool = False,
+def _transfer_span(per_run, tables, sharing: bool):
+    """The transfer span of a dispatch's host inputs: ``runs``, the rows
+    of ``per_run``; ``tables``, the rows of ``tables`` shipped (each run
+    ships its own data where its chunk is not ``sharing``; a chunk whose
+    tables the devices already hold ships none); ``bytes``, the host
+    bytes of both."""
+    runs = len(jax.tree.leaves(per_run)[0])
+    n = len(tables[0]) if tables else 0 if sharing else runs
+    return jax.profiler.TraceAnnotation(
+        "repro.sweep.transfer", runs=runs, tables=n,
+        bytes=sum(a.nbytes for a in jax.tree.leaves((tables, per_run))),
+    )
+
+
+def _place(tables, per_run, mesh: Optional[Mesh]):
+    """Host arrays onto the device(s): with a ``mesh``, the tables on
+    every device and the per-run inputs split on its runs axis, so entry
+    into the sharded executable moves no data."""
+    if mesh is None:
+        return jax.tree.map(jnp.asarray, (tables, per_run))
+    every, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("runs"))
+    return (
+        jax.tree.map(lambda a: jax.device_put(a, every), tables),
+        jax.tree.map(lambda a: jax.device_put(a, split), per_run),
+    )
+
+
+def _rows(preps: Sequence[Prepared], batch: _Stacked) -> tuple:
+    """What a chunk's tables hold: its shared positions, the ids of the
+    host objects at them row by row, and the objects (alive, so that no id
+    is reused while the devices hold their rows)."""
+    firsts = np.unique(batch.index, return_index=True)[1]
+    objs = [
+        c for i in firsts for c, s in zip(preps[i].consts, batch.shared) if s
+    ]
+    return batch.shared, tuple(map(id, objs)), objs
+
+
+def _run_chunks(
+    kernel: MethodKernel,
+    problems: Sequence[LeastSquaresProblem],
+    nets: Sequence[Network],
+    cfgs: Sequence,
+    iters: int,
+    reductions: Optional[Reduction],
+    D: int,
 ):
-    """Place stacked host runs (padding included) on the runs mesh and
-    ``tables`` replicated on every device, in a transfer span
-    (`_transfer_span`). Returns (tables, per_run) placed."""
-    everywhere = NamedSharding(mesh, P())
-    with _transfer_span(per_run, tables, sharing):
-        return (
-            tuple(jax.device_put(t, everywhere) for t in tables),
-            jax.tree.map(
-                lambda a: jax.device_put(
-                    a, NamedSharding(mesh, batch_specs(a, layout))
-                ),
-                per_run,
-            ),
-        )
+    """R runs through the one batched pipeline on D devices (§8, §9).
+
+    The jit statics come from the group's `max_statics_bound` before any
+    run is prepared, so every chunk runs on one executable. The first
+    run, prepared as the first of the first chunk, sizes the chunks
+    (`_chunk_runs`). Per chunk: prepare its runs, stack them (the last
+    chunk padded to a multiple of D by repeating its last run), place
+    them, execute, and bring the outputs back. Runs are prepared only
+    when their chunk dispatches, so host memory is O(chunk x iters) +
+    O(R x outputs). A chunk ships its tables unless the devices hold the
+    same rows already (`_rows`): the same host objects, by identity.
+    """
+    R = len(problems)
+    if not (len(nets) == len(cfgs) == R):
+        raise ValueError("problems, nets, cfgs must have equal length")
+    bound = _statics_bound(kernel, problems, cfgs, iters)
+    clock = reductions is not None
+    mesh = _runs_mesh() if D > 1 else None
+    donate = D > 1 and jax.default_backend() in ("tpu", "gpu")
+    prepared = (
+        kernel.prepare(p, n, c, iters) for p, n, c in zip(problems, nets, cfgs)
+    )
+    chunk = base = key = held = None  # held: (`_rows`, placed tables)
+    outs, kept, done = [], [], 0
+    while done < R:
+        with jax.profiler.TraceAnnotation("repro.sweep.prepare") as span:
+            preps = [next(prepared)]
+            if chunk is None:
+                base = preps[0].statics
+                key = _statics_key({**base, **bound})
+                chunk = _chunk_runs(R, D, _run_bytes(preps[0], clock))
+            preps += itertools.islice(prepared, chunk - 1)
+            span.set_metadata(runs=len(preps))
+            _check_statics(kernel, preps, base, bound)
+        n = len(preps)
+        done += n
+        with jax.profiler.TraceAnnotation("repro.sweep.stack"):
+            batch = _stack_runs(preps, clock, D)
+            per_run = _pad_runs(batch.per_run, n, D)
+        shared, sharing, ship = batch.shared, batch.index is not None, ()
+        if sharing:
+            rows = _rows(preps, batch)
+            if held is None or held[0][:2] != rows[:2]:
+                ship = batch.tables
+        if not clock:  # a Trace keeps only the host clocks of its run
+            kept += [
+                dataclasses.replace(pr, consts=(), steps=()) for pr in preps
+            ]
+        del preps, batch  # the chunk's host copies die before the next
+        with _transfer_span(per_run, ship, sharing):
+            put_t, put_r = _place(ship, per_run, mesh)
+        del per_run
+        if ship:
+            held = (rows, put_t)
+        args = (held[1], *put_r) if sharing else put_r
+        if clock:
+            fn = _batch_reduced_fn(kernel, key, reductions, shared, D, donate)
+        else:
+            fn = _batch_fn(kernel, key, shared, D, donate)
+        with jax.profiler.TraceAnnotation("repro.sweep.execute"):
+            outs.append(jax.tree.map(lambda a: np.asarray(a)[:n], fn(*args)))
+    out = jax.tree.map(lambda *a: np.concatenate(a), *outs)
+    if clock:
+        return out
+    x, z, (acc, test_err, z_err) = out
+    return [
+        _to_trace(pr, x[r], z[r], (acc[r], test_err[r], z_err[r]))
+        for r, pr in enumerate(kept)
+    ]
 
 
-def _shared_statics(statics: dict, prep: Prepared) -> dict:
-    """The statics a chunked run must agree on: everything but the
-    max-reconciled keys (whose runtime values legitimately differ)."""
-    return {
-        k: v for k, v in statics.items() if k not in prep.max_statics
-    }
+def run_batch(
+    kernel: MethodKernel,
+    problems: Sequence[LeastSquaresProblem],
+    nets: Sequence[Network],
+    cfgs: Sequence,
+    iters: int,
+    reductions: Optional[Reduction] = None,
+):
+    """R runs as a vmapped scan on one device: one jit trace, and one
+    dispatch per chunk of the memory budget (one for most grids).
+
+    Returns per-run `Trace`s, or — with ``reductions`` — one dict of
+    numpy arrays with a leading runs axis (DESIGN.md §12).
+    """
+    return _run_chunks(kernel, problems, nets, cfgs, iters, reductions, 1)
 
 
 def run_sharded(
@@ -680,72 +576,19 @@ def run_sharded(
 ):
     """R runs vmapped AND laid out over a device mesh on the runs axis.
 
-    The computation is literally `run_batch`'s vmapped scan, wrapped in
-    `shard_map` over a 1-D `Mesh` of all visible devices: each device
-    executes the scan on its local R/D runs (see `_sharded_fn` for why
-    shard_map rather than GSPMD propagation). Inputs are pre-placed with
-    `NamedSharding`s inferred by `repro.distributed.sharding.batch_specs`
-    so entry into the jitted shard_map moves no data. R is padded to a
-    device-count multiple by repeating the last run (padded outputs are
-    dropped), grids above the `REPRO_SHARD_MEM_MB` per-device budget are
-    split into device-aligned chunks, and input buffers are donated on
-    accelerator backends (XLA does not implement donation on CPU).
-    Bitwise equal to `run_batch` because no op crosses the runs axis;
-    with a single visible device it degrades to exactly `run_batch`.
-
-    With ``reductions`` set, execution routes to `_run_reduced_chunked`:
-    the same mesh layout, but runs are prepared lazily per chunk and the
-    scan emits fixed-size streaming summaries instead of a full `Trace`
-    (DESIGN.md §12) — the return value is one dict of (R, ...) numpy
-    arrays. The bitwise claim above is for the Trace path; the in-scan
-    fold fuses with the kernel math, so streaming summaries agree with
-    `run_batch` to last-ulp tolerance rather than bit-for-bit (XLA
-    fusion choices move with the per-device vmap batch size).
+    The same pipeline as `run_batch`, on a 1-D `Mesh` of all visible
+    devices: each chunk is padded to a device-count multiple by repeating
+    its last run (padded outputs are dropped), placed split on the runs
+    axis (tables on every device), and executed as the vmapped scan under
+    `shard_map` (see `_build` for why shard_map rather than GSPMD), with
+    input buffers donated on accelerator backends (XLA does not implement
+    donation on CPU). Bitwise equal to `run_batch` for Traces because no
+    op crosses the runs axis. Streaming summaries agree with `run_batch`
+    to last-ulp tolerance rather than bit-for-bit: the in-scan fold fuses
+    with the kernel math, and XLA's fusion choices move with the
+    per-device vmap batch size (DESIGN.md §12).
     """
-    D = len(jax.devices())
-    if D == 1 or len(problems) == 1:
-        # Structural fallback: one device means nothing to lay out; one
-        # run means padding would make every device compute a duplicate
-        # of the same scan for no wall-clock gain.
-        return run_batch(
-            kernel, problems, nets, cfgs, iters, reductions=reductions
-        )
-    if reductions is not None:
-        return _run_reduced_chunked(
-            kernel, problems, nets, cfgs, iters, reductions
-        )
-
-    # Tables are replicated on the D devices, so they ship D times.
-    preps, statics, batch = _stack_batch(
-        kernel, problems, nets, cfgs, iters, copies=D
-    )
-    R = len(preps)
-    mesh = _runs_mesh()
-    layout = AxisLayout(mesh, data=("runs",), model="model")
-    donate = jax.default_backend() in ("tpu", "gpu")
-    fn = _sharded_fn(
-        kernel, _statics_key(statics), D, batch.shared, len(batch.steps),
-        donate,
-    )
-
-    chunk = _chunk_runs(-(-R // D) * D, D, *_bytes_per_run(batch, statics))
-    sharing = batch.index is not None
-    placed = None  # the tables on every device, from the first chunk on
-    outs: List[Tuple] = []
-    for lo in range(0, R, chunk):
-        n = min(chunk, R - lo)
-        with jax.profiler.TraceAnnotation("repro.sweep.stack"):
-            part = _pad_runs(
-                jax.tree.map(lambda a: a[lo : lo + n], batch.per_run), n, D
-            )
-        ship = batch.tables if placed is None else ()  # later chunks: none
-        put_t, put_r = _put_sharded(part, mesh, layout, ship, sharing)
-        if placed is None:
-            placed = (put_t,) if sharing else ()
-        with jax.profiler.TraceAnnotation("repro.sweep.execute"):
-            x, z, (acc, te, ze) = fn(*placed, *put_r)
-            outs.append(
-                tuple(np.asarray(o)[:n] for o in (x, z, acc, te, ze))
-            )
-    cat = [np.concatenate([o[i] for o in outs]) for i in range(5)]
-    return _unstack_traces(preps, cat[0], cat[1], (cat[2], cat[3], cat[4]))
+    # Structural fallback: one run would make every device compute a
+    # duplicate of the same scan for no wall-clock gain.
+    D = len(jax.devices()) if len(problems) > 1 else 1
+    return _run_chunks(kernel, problems, nets, cfgs, iters, reductions, D)

@@ -91,7 +91,7 @@ def test_vmapped_matches_serial_elementwise(iters):
             _windows_past_the_data(c) for c in spec.cases() if c.S > 0
         )
     batched = run_sweep(spec)
-    serial = run_sweep(spec, serial=True)
+    serial = run_sweep(spec, mode="serial")
     assert batched.cases == serial.cases
     for case, tb, ts in zip(batched.cases, batched.traces, serial.traces):
         for field in ("accuracy", "test_error", "z_err", "comm_cost",
@@ -141,7 +141,7 @@ def test_baseline_methods_batch_and_match():
         for s in (0, 1)
     ]
     batched = run_sweep(cases)
-    serial = run_sweep(cases, serial=True)
+    serial = run_sweep(cases, mode="serial")
     assert batched.n_dispatches == 4  # one vmapped dispatch per method
     for case, tb, ts in zip(cases, batched.traces, serial.traces):
         np.testing.assert_allclose(

@@ -51,7 +51,7 @@ def test_batched_matches_serial_every_method():
     """vmap-of-step == scan-of-step elementwise, for all ten kernels."""
     cases = [_case(m, seed=s) for m in ALL_METHODS for s in (0, 1)]
     batched = run_sweep(cases)
-    serial = run_sweep(cases, serial=True)
+    serial = run_sweep(cases, mode="serial")
     # sI and csI share the ADMM family signature (S/scheme are runtime
     # inputs) and merge into one dispatch; every other method is its own.
     assert batched.n_dispatches == len(ALL_METHODS) - 1

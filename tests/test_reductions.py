@@ -30,7 +30,9 @@ from repro.experiments import (
     resample_runs,
     run_sweep,
 )
-from repro.methods import driver, get_kernel, run_batch, run_serial, run_sharded
+from repro.methods import (
+    KERNELS, driver, get_kernel, run_batch, run_serial, run_sharded,
+)
 from repro.methods.admm import ADMMRun
 
 ITERS = 40
@@ -203,7 +205,7 @@ def test_chunked_streaming_matches_unchunked(monkeypatch):
         kernel, probs, nets, cfgs, ITERS, reductions=FULL_SPEC
     )
     # A zero budget clamps every dispatch to D runs: 2 chunks here.
-    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "0")
+    monkeypatch.setattr(driver, "_MEM_BUDGET", 0)
     chunked = run_sharded(
         kernel, probs, nets, cfgs, ITERS, reductions=FULL_SPEC
     )
@@ -213,19 +215,30 @@ def test_chunked_streaming_matches_unchunked(monkeypatch):
         )
 
 
-def test_max_statics_bound_exact_for_admm():
-    """The chunked path's one-trace guarantee: the hook equals the
-    prepared MU for mixed-(M, S) runs (mu = M_bar // K, no sampling)."""
-    kernel = get_kernel("csI-ADMM")
+ARMS = (("cyclic", 1, None), ("cyclic", 2, None), ("approx", 2, 1e-3))
+
+
+@pytest.mark.parametrize("method", sorted(KERNELS))
+def test_max_statics_bound_exact_for_admm(method):
+    """The batched pipeline's one-statics rule: the jit statics come from
+    the hook before any run is prepared, so for every registered kernel
+    the hook equals the prepared ``max_statics`` for mixed-(M, S) runs
+    (mu = M_bar // K, no sampling) — the adaptive kernel over more than
+    one arm. Gossip and Walkman kernels have none, so the base default
+    holds."""
+    kernel = get_kernel(method)
     prob = allocate(DATASETS["usps"](0), 5, 3)
     net = make_network(5, 0.5, seed=0)
-    for M, S, scheme in ((60, 0, "uncoded"), (60, 1, "cyclic"),
-                         (120, 1, "cyclic")):
-        run = ADMMRun(ADMMConfig(M=M, K=3, S=S, scheme=scheme))
+    arms = ARMS if method == "a-csI-ADMM" else ()
+    # M divides by (S + 1) * K for every arm's S as well.
+    for M, S, scheme in ((36, 0, "uncoded"), (36, 1, "cyclic"),
+                         (72, 1, "cyclic")):
+        case = Case(method=method, dataset="usps", N=5, K=3, M=M, S=S,
+                    scheme=scheme, arms=arms)
+        run = kernel.config(case)
         bound = kernel.max_statics_bound(prob, run, 10)
         prep = kernel.prepare(prob, net, run, 10)
         assert bound == prep.max_statics, (M, S)
-    # Gossip kernels have no max_statics, so the base default holds.
     assert get_kernel("DGD").max_statics_bound(prob, None, 10) == {}
 
 
@@ -362,11 +375,11 @@ def test_chunked_streaming_uses_single_executable(monkeypatch):
     """Dispatch-count honesty: multi-chunk streaming must reuse ONE
     jitted executable (the max_statics_bound contract) — mixed-S chunks
     reconcile under one set of statics instead of retracing per chunk."""
-    driver._sharded_reduced_fn.cache_clear()
+    driver._batch_reduced_fn.cache_clear()
     kernel = get_kernel("csI-ADMM")
     D = len(jax.devices())
     probs, nets, cfgs = _admm_runs(D + 2)
-    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "0")
+    monkeypatch.setattr(driver, "_MEM_BUDGET", 0)
     run_sharded(kernel, probs, nets, cfgs, ITERS, reductions=FULL_SPEC)
-    info = driver._sharded_reduced_fn.cache_info()
+    info = driver._batch_reduced_fn.cache_info()
     assert info.currsize == 1

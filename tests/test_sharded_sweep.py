@@ -19,7 +19,7 @@ from repro.core.admm import ADMMConfig
 from repro.core.graph import make_network
 from repro.core.problems import DATASETS, allocate
 from repro.experiments import Case, SweepSpec, run_sweep
-from repro.methods import driver, get_kernel
+from repro.methods import Reduction, driver, get_kernel
 from repro.methods.admm import ADMMRun
 
 ITERS = 40
@@ -104,7 +104,7 @@ def test_chunked_execution_matches_unchunked(monkeypatch):
     must be invisible in the outputs."""
     spec = _spec(runs=2)
     whole = run_sweep(spec, mode="sharded")
-    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "1")
+    monkeypatch.setattr(driver, "_MEM_BUDGET", 2**20)
     chunked = run_sweep(spec, mode="sharded")
     for tw, tc in zip(whole.traces, chunked.traces):
         for field in TRACE_FIELDS:
@@ -113,18 +113,43 @@ def test_chunked_execution_matches_unchunked(monkeypatch):
             )
 
 
+@pytest.mark.parametrize("budget", [None, 0], ids=["one-chunk", "chunks"])
+@pytest.mark.parametrize("reduced", [False, True], ids=["traces", "summaries"])
+@pytest.mark.parametrize("mode", ["batched", "sharded"])
+def test_each_run_prepared_once(monkeypatch, mode, reduced, budget):
+    """Every tier prepares each run exactly once, in one chunk or in
+    several (a zero budget clamps a dispatch to the devices): the first
+    run sizes the chunks as the first run of the first one."""
+    kernel = get_kernel("csI-ADMM")
+    prepare, seen = kernel.prepare, []
+
+    def spy(problem, net, cfg, iters):
+        seen.append((id(problem), repr(cfg)))  # one key per case
+        return prepare(problem, net, cfg, iters)
+
+    monkeypatch.setattr(kernel, "prepare", spy)
+    if budget is not None:
+        monkeypatch.setattr(driver, "_MEM_BUDGET", budget)
+    spec = _spec()
+    red = Reduction(fields=("accuracy",), budgets=(0.01,)) if reduced else None
+    res = run_sweep(spec, mode=mode, reductions=red)
+    assert len(res.cases) == 9
+    assert len(seen) == 9 and len(set(seen)) == 9
+
+
 def test_chunk_rule_device_aligned(monkeypatch):
-    """Chunk sizes are multiples of D, at least D, at most the padded R.
-    Shared const tables sit on every device whatever the chunk, so they
-    come off the budget before it is split among runs."""
-    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "1")
-    assert driver._chunk_runs(16, 8, per_run_bytes=10 * 2**20) == 8
-    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "4096")
-    assert driver._chunk_runs(16, 8, per_run_bytes=10 * 2**20) == 16
-    assert driver._chunk_runs(24, 4, per_run_bytes=1) == 24
-    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "64")
-    assert driver._chunk_runs(256, 8, 2**20, shared_bytes=32 * 2**20) == 128
-    assert driver._chunk_runs(256, 8, 2**20, shared_bytes=2**40) == 8
+    """Chunk sizes are multiples of D, at least D, at most R padded to a
+    multiple of D, and as many runs as the budget holds with 2x slack."""
+    monkeypatch.setattr(driver, "_MEM_BUDGET", 2**20)
+    assert driver._chunk_runs(16, 8, run_bytes=10 * 2**20) == 8
+    monkeypatch.setattr(driver, "_MEM_BUDGET", 4096 * 2**20)
+    assert driver._chunk_runs(16, 8, run_bytes=10 * 2**20) == 16
+    assert driver._chunk_runs(24, 4, run_bytes=1) == 24
+    assert driver._chunk_runs(13, 4, run_bytes=1) == 16
+    monkeypatch.setattr(driver, "_MEM_BUDGET", 64 * 2**20)
+    assert driver._chunk_runs(512, 8, 2**20) == 256
+    assert driver._chunk_runs(512, 8, 2**20 + 1) == 248
+    assert driver._chunk_runs(1, 1, 2**40) == 1
 
 
 def test_single_device_fallback(monkeypatch):
@@ -143,10 +168,7 @@ def test_mode_validation():
     spec = _spec(runs=1, S_values=(0,))
     with pytest.raises(ValueError, match="unknown sweep mode"):
         run_sweep(spec, mode="bogus")
-    with pytest.raises(ValueError, match="contradicts"):
-        run_sweep(spec, serial=True, mode="batched")
-    assert run_sweep(spec, serial=True).mode == "serial"
-    assert run_sweep(spec, serial=True, mode="serial").mode == "serial"
+    assert run_sweep(spec, mode="serial").mode == "serial"
 
 
 def test_step_lowers_through_coded_admm_update():

@@ -1,8 +1,8 @@
 """Shared const tables of the batched engine (DESIGN.md §7, §9).
 
 The grid points of one seed hold the same `LeastSquaresProblem`, so their
-data arrays are the same host objects. `driver._stack_batch` ships such a
-const once, as a row of a table, with an int32 index per run; the
+data arrays are the same host objects. The batched pipeline ships such a
+const once (`driver._stack_consts`), as a row of a table, with an int32 index per run; the
 executable takes each run's rows on the device. The results must be
 those of per-run stacking: batched equals serial as the other tier tests
 require, sharded equals batched bitwise, and equal (R, U) jobs share one
@@ -44,6 +44,16 @@ WIDE = [dict(c, rho=rho) for c in CONFIGS for rho in (1.0, 0.5, 2.0)]
 DATA = (True, True, False, True, True, False, False)
 
 
+def _stacked(kernel, probs, nets, cfgs, clock=False):
+    """The group's prepared runs, jit statics and stacked inputs on one
+    device, as the batched pipeline builds them."""
+    preps = [kernel.prepare(p, n, c, ITERS) for p, n, c in zip(probs, nets, cfgs)]
+    bound = driver._statics_bound(kernel, probs, cfgs, ITERS)
+    return preps, {**preps[0].statics, **bound}, driver._stack_runs(
+        preps, clock, 1
+    )
+
+
 def _grid(seeds, configs):
     """Runs of ``seeds`` x ``configs``, materialized with the sweep's
     caches, so the configs of a seed hold one problem."""
@@ -81,14 +91,14 @@ def test_batched_equals_serial(configs, shared, own_T, reduced):
     if own_T:
         probs = [dataclasses.replace(p, T=p.T.copy()) for p in probs]
     R = len(probs)
-    _, _, batch = driver._stack_batch(kernel, probs, nets, cfgs, ITERS)
+    _, _, batch = _stacked(kernel, probs, nets, cfgs)
     assert batch.shared == shared
     if any(shared):
         assert [len(t) for t in batch.tables] == [3] * sum(shared)
         np.testing.assert_array_equal(batch.index, np.repeat(range(3), 4))
     else:
         assert batch.tables == () and batch.index is None
-        assert len(batch.args) == 2  # (consts, steps), as with no tables
+        assert len(batch.per_run) == 2  # (consts, steps), as with no tables
     assert [len(c) for c in batch.consts] == [R] * (7 - sum(shared))
 
     got = run_batch(
@@ -112,7 +122,7 @@ def test_batched_equals_serial(configs, shared, own_T, reduced):
 def test_tables_hold_each_runs_own_consts():
     """Row ``index[r]`` of every table is run r's const, bit for bit."""
     kernel, probs, nets, cfgs = _grid(range(3), CONFIGS)
-    preps, _, batch = driver._stack_batch(kernel, probs, nets, cfgs, ITERS)
+    preps, _, batch = _stacked(kernel, probs, nets, cfgs)
     positions = [i for i, s in enumerate(batch.shared) if s]
     for r, pr in enumerate(preps):
         for table, i in zip(batch.tables, positions):
@@ -182,11 +192,9 @@ def test_equal_jobs_reuse_one_executable():
         run_batch(*_grid(seeds, CONFIGS), ITERS, reductions=SPEC)
     assert driver._batch_reduced_fn.cache_info().currsize == 1
     kernel, probs, nets, cfgs = _grid(range(3), CONFIGS)
-    _, statics, batch = driver._stack_batch(
-        kernel, probs, nets, cfgs, ITERS, clock=True
-    )
+    _, statics, batch = _stacked(kernel, probs, nets, cfgs, clock=True)
     fn = driver._batch_reduced_fn(
-        kernel, driver._statics_key(statics), SPEC, batch.shared
+        kernel, driver._statics_key(statics), SPEC, batch.shared, 1, False
     )
     assert driver._batch_reduced_fn.cache_info().currsize == 1
     assert fn._cache_size() == 1
@@ -199,21 +207,21 @@ def test_equal_jobs_reuse_one_executable():
     ids=["tables", "per-run"],
 )
 def test_sharded_equals_batched_bitwise(monkeypatch, seeds, configs, shared):
-    """The eager sharded tier replicates tables on the 8 devices only
-    where that ships fewer rows than per-run stacking: 2 seeds x 12
-    configs ship 2 x 8 rows against 24, while 3 seeds x 4 configs would
-    ship 3 x 8 against 16 padded runs and stack per run. Either way the
+    """The sharded tier replicates tables on the 8 devices only where
+    that ships fewer rows than per-run stacking: 2 seeds x 12 configs
+    ship 2 x 8 rows against 24, while 3 seeds x 4 configs would ship
+    3 x 8 against 16 padded runs and stack per run. Either way the
     results are batched's, bit for bit."""
     kernel, probs, nets, cfgs = _grid(range(seeds), configs)
     seen = []
-    stack = driver._stack_batch
+    stack = driver._stack_runs
 
     def spy(*a, **kw):
         out = stack(*a, **kw)
-        seen.append(out[2].shared)
+        seen.append(out.shared)
         return out
 
-    monkeypatch.setattr(driver, "_stack_batch", spy)
+    monkeypatch.setattr(driver, "_stack_runs", spy)
     batched = run_batch(kernel, probs, nets, cfgs, ITERS)
     sharded = run_sharded(kernel, probs, nets, cfgs, ITERS)
     # run_batch ships its tables once: 2 or 3 rows against 24 or 12 runs.
@@ -241,11 +249,14 @@ def _flat(out):
 def test_tables_change_no_bit(monkeypatch, tier):
     """The same grid with each run's problem copied (nothing shared, so
     every run ships its own data) gives the same bits. In the sharded
-    tier a zero budget cuts 24 runs into 3 chunks of 8 devices, the
-    later two reusing the first's tables."""
+    tier a budget of 3 runs a device cuts 3 seeds x 12 configs into
+    chunks of 24 and 12 runs, which ship 2 and 1 table rows."""
     if tier == "sharded-chunks":
-        monkeypatch.setenv("REPRO_SHARD_MEM_MB", "0")
-        grid, run, kw = _grid(range(2), WIDE), run_sharded, {}
+        grid, run, kw = _grid(range(3), WIDE), run_sharded, {}
+        kernel, probs, nets, cfgs = grid
+        prep = kernel.prepare(probs[0], nets[0], cfgs[0], ITERS)
+        budget = 2 * 3 * driver._run_bytes(prep, clock=False)
+        monkeypatch.setattr(driver, "_MEM_BUDGET", budget)
     else:
         grid, run = _grid(range(3), CONFIGS), run_batch
         kw = {"reductions": SPEC} if tier.endswith("summaries") else {}

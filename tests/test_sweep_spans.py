@@ -2,8 +2,8 @@
 
 `run_sweep` writes ``repro.sweep.*`` spans with `jax.profiler.TraceAnnotation`
 at the layer boundaries of one call: the call, materialize, then per
-dispatch group (per chunk in the sharded tier) prepare, stack, transfer
-and execute. Each test records a real CPU profiler trace of a tiny sweep
+dispatch chunk prepare, stack, transfer and execute (a group is one
+chunk unless it outgrows the per-device memory budget). Each test records a real CPU profiler trace of a tiny sweep
 (with the benchmark's profiler options: no Python tracer) and reads the
 spans back with their keyword arguments, as the benchmark's trace
 reduction does. conftest.py forces 8 CPU devices, so the sharded tier is
@@ -77,12 +77,20 @@ def _stacked(cases, clock, copies=1):
     kernel = get_kernel(cases[0].method)
     nets, probs = {}, {}
     mats = [_materialize(c, nets, probs) for c in cases]
-    _, _, batch = driver._stack_batch(
-        kernel, [m[1] for m in mats], [m[0] for m in mats],
-        [kernel.config(c) for c in cases], cases[0].iters, clock=clock,
-        copies=copies,
-    )
-    return batch
+    preps = [
+        kernel.prepare(p, n, kernel.config(c), c.iters)
+        for c, (n, p) in zip(cases, mats)
+    ]
+    return driver._stack_runs(preps, clock, copies)
+
+
+def _budget(chunk, case, clock):
+    """A memory budget that holds ``chunk`` runs like ``case`` on each of
+    the 8 devices, so the sharded tier dispatches 8 x ``chunk`` runs."""
+    kernel = get_kernel(case.method)
+    net, prob = _materialize(case, {}, {})
+    prep = kernel.prepare(prob, net, kernel.config(case), case.iters)
+    return 2 * chunk * driver._run_bytes(prep, clock)
 
 
 def _nbytes(arrays):
@@ -105,7 +113,8 @@ def test_batched_tier_records_one_span_per_phase(tmp_path, reduced, share,
     assert by_name["repro.sweep.prepare"] == {"runs": 3}
     batch = _stacked(cases, clock=reduced)
     assert by_name["repro.sweep.transfer"] == {
-        "runs": 3, "tables": tables, "bytes": _nbytes(batch.args),
+        "runs": 3, "tables": tables,
+        "bytes": _nbytes((batch.tables, batch.per_run)),
     }
     # Each table ships once: only the per-run inputs grow with the runs.
     assert [len(t) for t in batch.tables] == (
@@ -116,60 +125,59 @@ def test_batched_tier_records_one_span_per_phase(tmp_path, reduced, share,
         assert by_name[name] == {}
 
 
-def test_sharded_chunks_record_spans_per_chunk(tmp_path, monkeypatch):
-    # A zero budget clamps every dispatch to the 8 devices: 9 runs go in
-    # two chunks, the second padded to 8 rows.
-    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "0")
-    cases = _cases(9)
+@pytest.mark.parametrize("share", [1, 32], ids=["own-data", "shared-data"])
+def test_sharded_chunks_record_spans_per_chunk(tmp_path, monkeypatch, share):
+    # own-data: a zero budget clamps every dispatch to the 8 devices, so 9
+    # runs go in two chunks, the second padded to 8 rows. shared-data: 32
+    # runs of one problem in two chunks of 16.
+    cases = _cases(9 if share == 1 else 32, share)
+    chunk = 8 if share == 1 else 16
+    budget = 0 if share == 1 else _budget(2, cases[0], clock=True)
+    monkeypatch.setattr(driver, "_MEM_BUDGET", budget)
     res, spans = _record(tmp_path, spec_or_cases=cases, mode="sharded",
                          reductions=RED)
     assert res.mode == "sharded" and len(jax.devices()) == 8
-    top, materialize, probe, *chunks = spans
-    assert (top[0], top[3]) == ("repro.sweep", {"runs": 9})
-    assert materialize[0] == "repro.sweep.materialize"
-    assert (probe[0], probe[3]) == ("repro.sweep.prepare", {"runs": 1})
-    assert [s[0] for s in chunks] == list(PHASES) * 2
-    assert all(_inside(s, top) for s in spans[1:])
-    assert [s[3]["runs"] for s in chunks if s[0] == "repro.sweep.prepare"] == [8, 1]
-    sent = [s[3] for s in chunks if s[0] == "repro.sweep.transfer"]
-    # The lazy tier prepares per chunk and stacks every run's own data.
-    assert [st["runs"] for st in sent] == [8, 8]
-    assert [st["tables"] for st in sent] == [8, 8]
+    # Each run is prepared once, inside its chunk's prepare span.
+    _check_groups(spans, groups=2)
+    assert (spans[0][0], spans[0][3]) == ("repro.sweep", {"runs": len(cases)})
+    assert [s[3]["runs"] for s in spans if s[0] == "repro.sweep.prepare"] == [
+        chunk, len(cases) - chunk,
+    ]
+    sent = [s[3] for s in spans if s[0] == "repro.sweep.transfer"]
+    assert [st["runs"] for st in sent] == [chunk, chunk]
     one = _stacked(cases[:1], clock=True)
     per_run = _nbytes((one.consts, one.steps))
-    assert [st["bytes"] for st in sent] == [8 * per_run, 8 * per_run]
+    if share == 1:
+        # Every run stacks its own data.
+        assert [st["tables"] for st in sent] == [8, 8]
+        assert [st["bytes"] for st in sent] == [8 * per_run, 8 * per_run]
+    else:
+        # The first chunk ships the one problem as a table, which the
+        # second reuses: each ships its runs' index and their own consts.
+        first = _stacked(cases[:chunk], clock=True, copies=8)
+        assert first.index is not None
+        assert [st["tables"] for st in sent] == [1, 0]
+        assert [st["bytes"] for st in sent] == [
+            _nbytes((first.tables, first.per_run)), _nbytes(first.per_run),
+        ]
 
 
 @pytest.mark.parametrize("share", [1, 3, 9],
                          ids=["own-data", "few-sharers", "shared-data"])
 def test_sharded_trace_path_records_spans_per_chunk(tmp_path, monkeypatch,
                                                     share):
-    monkeypatch.setenv("REPRO_SHARD_MEM_MB", "0")
+    monkeypatch.setattr(driver, "_MEM_BUDGET", 0)
     cases = _cases(9, share)
     _, spans = _record(tmp_path, spec_or_cases=cases, mode="sharded")
-    names = [s[0] for s in spans]
-    # prepare and stack of the whole group, then per chunk the padded
-    # slice (stack), transfer and execute.
-    assert names == [
-        "repro.sweep", "repro.sweep.materialize", "repro.sweep.prepare",
-        "repro.sweep.stack",
-    ] + ["repro.sweep.stack", "repro.sweep.transfer", "repro.sweep.execute"] * 2
+    # Per chunk of the 8 devices: prepare, stack, transfer and execute.
+    _check_groups(spans, groups=2)
     sent = [s[3] for s in spans if s[0] == "repro.sweep.transfer"]
-    batch = _stacked(cases, clock=False, copies=8)
-    per_run = _nbytes(batch.per_run) // 9
-    if share < 9:
-        # 9 problems, or 3 that replicated on 8 devices would ship 24
-        # rows against 16 padded runs: every run ships its own data.
-        assert batch.index is None
-        assert sent == [{"runs": 8, "tables": 8, "bytes": 8 * per_run}] * 2
-    else:
-        # One problem for 9 runs: the first chunk ships the table (8
-        # copies against 16 rows), which every later chunk reuses.
-        assert sent == [
-            {"runs": 8, "tables": 1,
-             "bytes": _nbytes(batch.tables) + 8 * per_run},
-            {"runs": 8, "tables": 0, "bytes": 8 * per_run},
-        ]
+    first = _stacked(cases[:8], clock=False, copies=8)
+    per_run = _nbytes(first.per_run) // 8
+    # 8 problems, 3, or one that replicated on 8 devices would ship at
+    # least 8 rows against 8 runs: every run ships its own data.
+    assert first.index is None
+    assert sent == [{"runs": 8, "tables": 8, "bytes": 8 * per_run}] * 2
 
 
 def test_serial_tier_records_no_driver_spans(tmp_path):

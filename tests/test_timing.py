@@ -284,7 +284,7 @@ def test_code_frontier_single_dispatch_and_tier_agreement():
     batched = run_sweep(spec, mode="batched")
     assert batched.n_dispatches == 1
     assert len(batched.cases) == 20
-    modes = [batched, run_sweep(spec, serial=True)]
+    modes = [batched, run_sweep(spec, mode="serial")]
     if len(jax.devices()) > 1:
         modes.append(run_sweep(spec, mode="sharded"))
     reds = [
@@ -326,7 +326,7 @@ def test_fig3e_runtime_reduction_and_tier_agreement():
     serial/batched(/sharded) tiers agree on it elementwise."""
     spec = get_sweep("fig3e_runtime", iters=60, runs=2)
     batched = run_sweep(spec, mode="batched")
-    serial = run_sweep(spec, serial=True)
+    serial = run_sweep(spec, mode="serial")
     modes = [batched, serial]
     if len(jax.devices()) > 1:
         modes.append(run_sweep(spec, mode="sharded"))

@@ -200,9 +200,11 @@ def test_batched_step_fetches_windows_not_rows(
             probs.append(prob)
             nets.append(net)
             cfgs.append(kernel.config(case))
-    _, statics, batch = driver._stack_batch(
-        kernel, probs, nets, cfgs, 8, clock=True
-    )
+    preps = [kernel.prepare(p, n, c, 8) for p, n, c in zip(probs, nets, cfgs)]
+    statics = {
+        **preps[0].statics, **driver._statics_bound(kernel, probs, cfgs, 8)
+    }
+    batch = driver._stack_runs(preps, True, 1)
     assert batch.index is not None  # the datasets ship as shared tables
     spec = Reduction(fields=("accuracy", "test_error"), budgets=(0.5,))
     fn = driver._batched(
@@ -210,7 +212,9 @@ def test_batched_step_fetches_windows_not_rows(
         batch.shared,
         kernel.row_consts,
     )
-    args = jax.tree.map(lambda a: _f32(a, one_chip), batch.args)
+    args = jax.tree.map(
+        lambda a: _f32(a, one_chip), (batch.tables, *batch.per_run)
+    )
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     MU = statics["MU"]
