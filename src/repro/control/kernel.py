@@ -252,6 +252,7 @@ class AdaptiveADMM(IncrementalADMM):
             max_statics=dict(MU=int(tab["mu_arms"].max())),
             comm=np.cumsum(np.full(iters, self._comm_per_iter(run, problem))),
             sim_time=sim_time,
+            decode_patterns=sum(s["decode_patterns"] for s in tab["scheds"]),
         )
 
     def max_statics_bound(self, problem, run: AdaptiveRun, iters: int) -> dict:

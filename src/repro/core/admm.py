@@ -110,7 +110,8 @@ def make_schedule(
     """Host-side per-iteration schedule: agents, batches, decode vectors, time.
 
     Returns dict of numpy arrays consumed by the jitted scan + the
-    time/communication accounting.
+    time/communication accounting, and ``decode_patterns``: the number of
+    distinct alive patterns whose decode was solved (0 when uncoded).
 
     With churn enabled on the timing model (DESIGN.md §13), ECNs and
     agents crash/recover as an alternating-renewal process sampled on
@@ -185,6 +186,7 @@ def make_schedule(
             act = act * ~all_dead
             resp = np.where(all_dead, straggler.epsilon, resp)
         alive = recv
+        n_patterns = 0
     else:
         order = np.argsort(ecn_t, axis=1)
         alive = np.zeros((iters, K), dtype=bool)
@@ -218,27 +220,25 @@ def make_schedule(
             )
             alive = np.where(use_dl[:, None], arrived, alive)
             resp = np.where(use_dl, dl, resp)
-        # Decode vectors depend only on the alive pattern, so solve the
-        # lstsq once per distinct pattern — a sweep samples thousands of
-        # iterations but only ever sees C(K, S)-ish patterns (plus the
-        # deadline-truncated and churn-censored ones). Under churn a
-        # surviving pattern can fall outside the family's decodable set
-        # (too few responders, or a subset the code cannot invert):
-        # those iterations become skipped activations with zero decode
+        # Decode vectors depend only on the alive pattern, so solve once
+        # per distinct pattern, all in one stacked call — a sweep samples
+        # thousands of iterations but only ever sees C(K, S)-ish patterns
+        # (plus the deadline-truncated and churn-censored ones), keyed
+        # here as integers (bit j = ECN j). Under churn a surviving
+        # pattern can fall outside the family's decodable set (too few
+        # responders, or a subset the code cannot invert): those
+        # iterations become skipped activations with zero decode
         # weights, recording the epsilon cap as the agent's futile wait.
-        patterns, inverse = np.unique(alive, axis=0, return_inverse=True)
-        vecs, decodable = [], []
-        for a in patterns:
-            vec = None
-            if a.sum() >= code.min_responses:
-                try:
-                    vec = code.decode_vector(a)
-                except ValueError:
-                    vec = None
-            decodable.append(vec is not None)
-            vecs.append(vec if vec is not None else np.zeros(K))
-        decode = np.stack(vecs)[inverse]
-        ok = np.asarray(decodable)[inverse]
+        if K > 64:
+            raise ValueError(f"alive patterns are keyed in 64 bits; K={K}")
+        bits = np.arange(K, dtype=np.uint64)
+        keys, inverse = np.unique(
+            alive @ (np.uint64(1) << bits), return_inverse=True
+        )
+        vecs, decodable = code.decode_vectors((keys[:, None] >> bits) & 1)
+        n_patterns = len(keys)
+        decode = vecs[inverse]
+        ok = decodable[inverse]
         if not ok.all():
             act = act * ok
             resp = np.where(ok, resp, straggler.epsilon)
@@ -268,6 +268,7 @@ def make_schedule(
         link_time=link_t,
         mu=mu,
         P=P,
+        decode_patterns=n_patterns,
     )
 
 

@@ -128,6 +128,39 @@ class GradientCode:
     def _decode_tol(self) -> float:
         return 1e-6 if self.exact else self.err_bound * (1 + 1e-6) + 1e-9
 
+    def _solve(self, alive: np.ndarray):
+        """Min-norm least-squares decode of every row of ``alive`` (P, K).
+
+        Row p solves ``a^T B[alive_p] ~= 1^T`` as ``np.linalg.lstsq``
+        does with ``rcond=None``: one SVD of ``B^T`` with the dead
+        columns zeroed, stacked (P, K, K), with lstsq's cutoff
+        eps * K * s_max. A pattern of n responses has rank <= n, so its
+        K - n trailing singular values (the dead columns') are zeroed
+        before the cutoff. Returns the vectors, zero on dead ECNs, their
+        residuals ||a^T B - 1^T||_2 and the response counts.
+        """
+        alive = np.asarray(alive, dtype=bool).reshape(-1, self.K)
+        n = alive.sum(axis=1)
+        U, s, Vt = np.linalg.svd(np.where(alive[:, None, :], self.B.T, 0.0))
+        s = np.where(np.arange(self.K) < n[:, None], s, 0.0)
+        keep = s > np.finfo(np.float64).eps * self.K * s[:, :1]
+        coef = np.where(keep, U.sum(axis=1) / np.where(keep, s, 1.0), 0.0)
+        vecs = np.where(alive, np.einsum("pji,pj->pi", Vt, coef), 0.0)
+        return vecs, np.linalg.norm(vecs @ self.B - 1.0, axis=1), n
+
+    def decode_vectors(self, alive: np.ndarray):
+        """Decode vectors of P alive patterns in one stacked solve.
+
+        ``alive`` is a (P, K) boolean mask. Returns ``(vecs, ok)``:
+        ``ok[p]`` holds where pattern p has >= ``min_responses``
+        responses and decodes within the family's tolerance (the rule of
+        :meth:`decode_vector`); ``vecs[p]`` is its decode vector, and
+        zero where ``ok[p]`` is False.
+        """
+        vecs, resid, n = self._solve(alive)
+        ok = (n >= self.min_responses) & (resid <= self._decode_tol())
+        return np.where(ok[:, None], vecs, 0.0), ok
+
     def decode_vector(self, alive: np.ndarray) -> np.ndarray:
         """a with a^T B ~= 1^T and a supported on alive ECNs.
 
@@ -142,19 +175,14 @@ class GradientCode:
                 f"need >= r_min={self.min_responses} responses, "
                 f"got {int(alive.sum())}"
             )
-        idx = np.nonzero(alive)[0]
         # Least-squares decode: exactness (or the certified bound) is
         # asserted, so the returned vector is always usable.
-        ones = np.ones(self.K)
-        a_idx, *_ = np.linalg.lstsq(self.B[idx].T, ones, rcond=None)
-        resid = np.linalg.norm(self.B[idx].T @ a_idx - ones)
+        (a,), (resid,), _ = self._solve(alive)
         if resid > self._decode_tol():
             raise ValueError(
-                f"alive set {idx.tolist()} is not decodable "
+                f"alive set {np.nonzero(alive)[0].tolist()} is not decodable "
                 f"(residual {resid:.3g} > certified {self._decode_tol():.3g})"
             )
-        a = np.zeros(self.K)
-        a[idx] = a_idx
         return a
 
     def decode_error(self, alive: np.ndarray) -> float:
@@ -214,13 +242,12 @@ class GradientCode:
         checks = [self.S]
         if self.min_responses < self.R:
             checks.append(self.K - self.min_responses)
-        for n_dead in checks:
-            for alive in self._patterns(n_dead, max_patterns, rng):
-                try:
-                    self.decode_vector(alive)
-                except ValueError:
-                    return False
-        return True
+        return all(
+            self.decode_vectors(
+                np.stack(list(self._patterns(n_dead, max_patterns, rng)))
+            )[1].all()
+            for n_dead in checks
+        )
 
 
 def _ncr(n: int, r: int) -> int:
@@ -357,13 +384,9 @@ def approx_code(
         return GradientCode(
             "approx", K, S, B, r_min=r_min, err_bound=float(np.sqrt(K))
         )
-    ones = np.ones(K)
-    worst = 0.0
-    probe = GradientCode("approx", K, S, B, r_min=r_min, err_bound=np.inf)
-    for alive in probe._patterns(K - r_min, max_patterns, None):
-        idx = np.nonzero(alive)[0]
-        a, *_ = np.linalg.lstsq(B[idx].T, ones, rcond=None)
-        worst = max(worst, float(np.linalg.norm(B[idx].T @ a - ones)))
+    probe = GradientCode("approx", K, S, B, r_min=r_min)
+    alive = np.stack(list(probe._patterns(K - r_min, max_patterns, None)))
+    worst = float(probe._solve(alive)[1].max())
     return GradientCode("approx", K, S, B, r_min=r_min, err_bound=worst)
 
 

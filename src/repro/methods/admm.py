@@ -200,6 +200,7 @@ class IncrementalADMM(MethodKernel):
             max_statics=dict(MU=int(sched["mu"])),
             comm=np.cumsum(np.full(iters, self._comm_per_iter(run, problem))),
             sim_time=sim_time,
+            decode_patterns=sched["decode_patterns"],
         )
 
     def max_statics_bound(

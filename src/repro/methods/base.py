@@ -63,6 +63,9 @@ class Prepared:
         trace (DESIGN.md §7, §8).
       comm: cumulative communication units per iteration, host accounting.
       sim_time: cumulative simulated seconds per iteration.
+      decode_patterns: distinct alive patterns whose coded decode the
+        host solved for this run (0 for methods without one); the
+        prepare span of the batched driver sums it (DESIGN.md §16).
     """
 
     consts: Tuple[np.ndarray, ...]
@@ -71,6 +74,7 @@ class Prepared:
     max_statics: Dict[str, int]
     comm: np.ndarray
     sim_time: np.ndarray
+    decode_patterns: int = 0
 
 
 class TableRow(NamedTuple):

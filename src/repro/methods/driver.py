@@ -510,7 +510,10 @@ def _run_chunks(
                 key = _statics_key({**base, **bound})
                 chunk = _chunk_runs(R, D, _run_bytes(preps[0], clock))
             preps += itertools.islice(prepared, chunk - 1)
-            span.set_metadata(runs=len(preps))
+            span.set_metadata(
+                runs=len(preps),
+                decode_patterns=sum(p.decode_patterns for p in preps),
+            )
             _check_statics(kernel, preps, base, bound)
         n = len(preps)
         done += n

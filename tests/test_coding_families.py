@@ -168,6 +168,86 @@ def test_mds_decodes_any_superset_of_R():
 
 
 # -------------------------------------------------------------------------
+# Stacked decode parity: decode_vectors against the per-pattern lstsq path
+# -------------------------------------------------------------------------
+
+
+def _lstsq_decode_vector(code: GradientCode, alive: np.ndarray) -> np.ndarray:
+    """Frozen copy of the per-pattern decode that `decode_vectors`
+    replaced: one ``np.linalg.lstsq`` per alive mask."""
+    alive = np.asarray(alive, dtype=bool)
+    if alive.sum() < code.min_responses:
+        raise ValueError(
+            f"need >= r_min={code.min_responses} responses, "
+            f"got {int(alive.sum())}"
+        )
+    idx = np.nonzero(alive)[0]
+    ones = np.ones(code.K)
+    a_idx, *_ = np.linalg.lstsq(code.B[idx].T, ones, rcond=None)
+    resid = np.linalg.norm(code.B[idx].T @ a_idx - ones)
+    if resid > code._decode_tol():
+        raise ValueError(
+            f"alive set {idx.tolist()} is not decodable "
+            f"(residual {resid:.3g} > certified {code._decode_tol():.3g})"
+        )
+    a = np.zeros(code.K)
+    a[idx] = a_idx
+    return a
+
+
+def _lstsq_cond(code: GradientCode, alive: np.ndarray) -> float:
+    """Condition number of the solve lstsq makes: largest over smallest
+    singular value of ``B[alive]^T`` that its cutoff keeps."""
+    s = np.linalg.svd(code.B[alive].T, compute_uv=False)
+    s = s[s > np.finfo(np.float64).eps * code.K * s[0]]
+    return float(s[0] / s[-1])
+
+
+SMALL_CODES = [
+    (name, K, S)
+    for name in FAMILIES
+    for K in range(1, 9)
+    for S in range(K)
+    if _feasible(name, K, S)
+]
+
+
+@pytest.mark.parametrize(
+    "name,K,S", SMALL_CODES, ids=[f"{n}-K{K}-S{S}" for n, K, S in SMALL_CODES]
+)
+def test_stacked_decode_matches_per_pattern_lstsq(name, K, S):
+    """Every alive mask of every small code: `decode_vectors` accepts
+    exactly the patterns the per-pattern lstsq accepts, with the same
+    vectors, and `decode_vector` raises lstsq's messages on the rest.
+
+    A min-norm solve is fixed only to about cond * eps, so lstsq's own
+    vector moves that much under rounding; the vectors must agree within
+    1e-12 * cond, the pattern's condition number (below 100 on most
+    patterns; the S = K - 1 cyclic draws are numerically rank one, cond
+    ~ 1e14, where both solves keep a rounding direction).
+    """
+    code = make_code(name, K, S, seed=0)
+    masks = ((np.arange(2**K)[:, None] >> np.arange(K)) & 1).astype(bool)
+    vecs, ok = code.decode_vectors(masks)
+    assert vecs.shape == (2**K, K) and ok.shape == (2**K,)
+    for alive, vec, accepted in zip(masks, vecs, ok):
+        try:
+            want = _lstsq_decode_vector(code, alive)
+        except ValueError as err:
+            assert not accepted, alive
+            assert (vec == 0).all()
+            with pytest.raises(ValueError) as got:
+                code.decode_vector(alive)
+            assert str(got.value) == str(err)
+            continue
+        assert accepted, alive
+        assert (vec[~alive] == 0).all()
+        tol = 1e-12 * max(1.0, _lstsq_cond(code, alive))
+        np.testing.assert_allclose(vec, want, rtol=0, atol=tol)
+        np.testing.assert_array_equal(code.decode_vector(alive), vec)
+
+
+# -------------------------------------------------------------------------
 # make_code feasibility errors (satellite: clear messages, regression)
 # -------------------------------------------------------------------------
 

@@ -11,9 +11,12 @@ the real one.
 """
 
 import jax
+import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from repro.core.admm import make_schedule
+from repro.core.coding import make_code
 from repro.experiments import Case, run_sweep
 from repro.experiments.sweep import _materialize
 from repro.methods import Reduction, driver, get_kernel
@@ -23,15 +26,31 @@ PHASES = ("repro.sweep.prepare", "repro.sweep.stack", "repro.sweep.transfer",
 RED = Reduction(fields=("accuracy",), budgets=(0.01,), x="sim_time")
 
 
-def _cases(n, share=1):
+def _cases(n, share=1, scheme="cyclic", S=1):
     """``n`` runs; each ``share`` consecutive runs hold one seed's problem
     (they differ in rho)."""
     return [
-        Case(method="csI-ADMM", dataset="usps", N=5, K=6, M=36, S=1,
-             scheme="cyclic", iters=20, seed=i // share,
+        Case(method="csI-ADMM", dataset="usps", N=5, K=6, M=36, S=S,
+             scheme=scheme, iters=20, seed=i // share,
              rho=1.0 + i % share)
         for i in range(n)
     ]
+
+
+def _patterns(cases):
+    """Distinct alive rows of the runs' schedules, summed: the decodes
+    their prepare solves (none for an uncoded run)."""
+    total = 0
+    for case in cases:
+        net, prob = _materialize(case, {}, {})
+        run = get_kernel(case.method).config(case)
+        if run.cfg.scheme != "uncoded":
+            code = make_code(run.cfg.scheme, run.cfg.K, run.cfg.S,
+                             seed=run.cfg.seed)
+            sched = make_schedule(run.cfg, net, code, run.timing,
+                                  case.iters, prob.b)
+            total += len(np.unique(sched["alive"], axis=0))
+    return total
 
 
 def _record(tmp_path, **kw):
@@ -110,7 +129,10 @@ def test_batched_tier_records_one_span_per_phase(tmp_path, reduced, share,
     _check_groups(spans, groups=1)
     by_name = {s[0]: s[3] for s in spans}
     assert by_name["repro.sweep"] == {"runs": 3}
-    assert by_name["repro.sweep.prepare"] == {"runs": 3}
+    assert by_name["repro.sweep.prepare"] == {
+        "runs": 3, "decode_patterns": _patterns(cases),
+    }
+    assert _patterns(cases) > 3
     batch = _stacked(cases, clock=reduced)
     assert by_name["repro.sweep.transfer"] == {
         "runs": 3, "tables": tables,
@@ -140,8 +162,10 @@ def test_sharded_chunks_record_spans_per_chunk(tmp_path, monkeypatch, share):
     # Each run is prepared once, inside its chunk's prepare span.
     _check_groups(spans, groups=2)
     assert (spans[0][0], spans[0][3]) == ("repro.sweep", {"runs": len(cases)})
-    assert [s[3]["runs"] for s in spans if s[0] == "repro.sweep.prepare"] == [
-        chunk, len(cases) - chunk,
+    assert [s[3] for s in spans if s[0] == "repro.sweep.prepare"] == [
+        {"runs": chunk, "decode_patterns": _patterns(cases[:chunk])},
+        {"runs": len(cases) - chunk,
+         "decode_patterns": _patterns(cases[chunk:])},
     ]
     sent = [s[3] for s in spans if s[0] == "repro.sweep.transfer"]
     assert [st["runs"] for st in sent] == [chunk, chunk]
@@ -178,6 +202,15 @@ def test_sharded_trace_path_records_spans_per_chunk(tmp_path, monkeypatch,
     # least 8 rows against 8 runs: every run ships its own data.
     assert first.index is None
     assert sent == [{"runs": 8, "tables": 8, "bytes": 8 * per_run}] * 2
+
+
+def test_uncoded_job_solves_no_decode_patterns(tmp_path):
+    cases = _cases(2, scheme="uncoded", S=0)
+    _, spans = _record(tmp_path, spec_or_cases=cases, mode="batched",
+                       reductions=RED)
+    _check_groups(spans, groups=1)
+    (prep,) = [s[3] for s in spans if s[0] == "repro.sweep.prepare"]
+    assert prep == {"runs": 2, "decode_patterns": 0}
 
 
 def test_serial_tier_records_no_driver_spans(tmp_path):

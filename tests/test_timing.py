@@ -392,3 +392,138 @@ def test_hetero_grid_single_dispatch():
     ]
     assert all(hom <= het for hom, het in pairs)
     assert any(hom < het for hom, het in pairs)
+
+
+# -------------------------------------------------------------------------
+# make_schedule parity with the per-pattern decode it replaced
+# -------------------------------------------------------------------------
+
+
+def _per_pattern_schedule(cfg, net, code, straggler, iters, b):
+    """Frozen copy of `make_schedule` as it was before the decode moved to
+    one stacked solve: `np.unique` over the alive rows, then one
+    `decode_vector` (one lstsq) per distinct pattern."""
+    from repro.core.timing import sample_times
+
+    K = cfg.K
+    P = b // K
+    mu = cfg.M_bar // K
+    nb = max(P // mu, 1)
+    route = np.array(net.hamiltonian, dtype=np.int32)
+    agents = np.tile(route, int(np.ceil(iters / len(route))))[:iters]
+    offsets = (((np.arange(iters) // net.N) % nb) * mu).astype(np.int32)
+    ecn_t, link_t = sample_times(straggler, iters, K, seed=cfg.seed + 1)
+    act = np.ones(iters)
+    if straggler.churn_rate > 0:
+        churn_rng = np.random.default_rng([6, cfg.seed])
+        prov = np.cumsum(
+            np.minimum(ecn_t.max(axis=1), straggler.epsilon) + link_t
+        )
+        starts = np.concatenate([[0.0], prov[:-1]])
+        ecn_up = straggler.sample_churn(starts, K, churn_rng)
+        agent_up = straggler.sample_churn(starts, net.N, churn_rng)
+        act = agent_up[np.arange(iters), agents].astype(float)
+        ecn_t = np.where(ecn_up, ecn_t, np.inf)
+    if cfg.scheme == "uncoded":
+        recv = ecn_t <= straggler.epsilon
+        none = ~recv.any(axis=1)
+        all_dead = np.isinf(ecn_t).all(axis=1)
+        fb = none & ~all_dead
+        recv[fb, np.argmin(ecn_t[fb], axis=1)] = True
+        decode = recv * (K / np.maximum(recv.sum(axis=1, keepdims=True), 1))
+        resp = np.minimum(ecn_t.max(axis=1), straggler.epsilon)
+        resp = np.where(fb, ecn_t.min(axis=1), resp)
+        if all_dead.any():
+            act = act * ~all_dead
+            resp = np.where(all_dead, straggler.epsilon, resp)
+        alive = recv
+    else:
+        order = np.argsort(ecn_t, axis=1)
+        alive = np.zeros((iters, K), dtype=bool)
+        np.put_along_axis(alive, order[:, : code.R], True, axis=1)
+        alive &= np.isfinite(ecn_t)
+        r_th = np.take_along_axis(ecn_t, order[:, code.R - 1 : code.R], axis=1)
+        resp = np.minimum(r_th[:, 0], straggler.epsilon)
+        dl = straggler.deadline
+        if dl is not None and code.min_responses < code.R:
+            arrived = ecn_t <= dl
+            n_arr = arrived.sum(axis=1)
+            use_dl = (
+                (n_arr >= code.min_responses) & (n_arr < code.R) & (dl < resp)
+            )
+            alive = np.where(use_dl[:, None], arrived, alive)
+            resp = np.where(use_dl, dl, resp)
+        patterns, inverse = np.unique(alive, axis=0, return_inverse=True)
+        vecs, decodable = [], []
+        for a in patterns:
+            vec = None
+            if a.sum() >= code.min_responses:
+                try:
+                    vec = code.decode_vector(a)
+                except ValueError:
+                    vec = None
+            decodable.append(vec is not None)
+            vecs.append(vec if vec is not None else np.zeros(K))
+        decode = np.stack(vecs)[inverse]
+        ok = np.asarray(decodable)[inverse]
+        if not ok.all():
+            act = act * ok
+            resp = np.where(ok, resp, straggler.epsilon)
+    if straggler.churn_rate > 0:
+        agent_dead = act == 0.0
+        resp = np.where(agent_up[np.arange(iters), agents], resp, 0.0)
+        decode = np.where(agent_dead[:, None], 0.0, decode)
+    return dict(
+        agents=agents, offsets=offsets, decode=decode, alive=alive, act=act,
+        resp_time=resp, link_time=link_t,
+    ), (len(patterns) if cfg.scheme != "uncoded" else 0)
+
+
+@pytest.mark.parametrize("churn", [0.0, 20.0], ids=["steady", "churn"])
+@pytest.mark.parametrize(
+    "scheme,S,deadline",
+    [("cyclic", 2, None), ("mds", 2, None), ("approx", 2, 3e-4),
+     ("uncoded", 0, None)],
+)
+def test_schedule_matches_per_pattern_decode(scheme, S, deadline, churn):
+    """The stacked decode changes no draw and no decision: every array
+    of the schedule is the per-pattern path's, bit for bit, but the
+    decode vectors, which agree within 1e-12; and ``decode_patterns``
+    counts the distinct alive rows."""
+    K, iters = 6, 600
+    cfg = ADMMConfig(M=(S + 1) * K * 4, K=K, S=S, scheme=scheme, seed=3)
+    net = make_network(10, 0.5, seed=0)
+    code = make_code(scheme, K, S, seed=cfg.seed)
+    model = TimingModel(
+        p_straggle=0.3, delay=5e-3, response="lognormal",
+        speed_classes=(1.0, 1.0, 4.0), deadline=deadline,
+        churn_rate=churn, mttr=0.02,
+    )
+    got = make_schedule(cfg, net, code, model, iters, b=cfg.M * 2)
+    want, n_patterns = _per_pattern_schedule(
+        cfg, net, code, model, iters, b=cfg.M * 2
+    )
+    for f in ("agents", "offsets", "alive", "act", "resp_time", "link_time"):
+        np.testing.assert_array_equal(got[f], want[f], err_msg=f)
+    np.testing.assert_allclose(got["decode"], want["decode"], rtol=0,
+                               atol=1e-12)
+    assert got["decode_patterns"] == n_patterns
+    if scheme == "uncoded":
+        assert n_patterns == 0
+    else:
+        assert n_patterns == len(np.unique(got["alive"], axis=0)) > 1
+        # Churn censors some patterns below what decodes: skipped.
+        few = got["alive"].sum(axis=1) < code.min_responses
+        assert few.any() == bool(churn) and not few.all()
+        assert (got["act"][few] == 0).all()
+
+
+def test_schedule_refuses_patterns_wider_than_its_keys():
+    """Alive patterns are keyed as 64-bit integers: a code of 65 ECNs is
+    refused rather than decoded from colliding keys."""
+    K = 65
+    cfg = ADMMConfig(M=2 * K, K=K, S=1, scheme="cyclic")
+    code = make_code("cyclic", K, 1, seed=0)
+    with pytest.raises(ValueError, match="keyed in 64 bits; K=65"):
+        make_schedule(cfg, make_network(5, 0.5, seed=0), code,
+                      TimingModel(), 10, b=cfg.M * 2)
